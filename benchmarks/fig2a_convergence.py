@@ -15,6 +15,7 @@ SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 SCRIPT = textwrap.dedent("""
     import os
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json, sys
     sys.path.insert(0, {src!r})

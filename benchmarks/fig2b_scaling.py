@@ -16,11 +16,14 @@ import sys
 import textwrap
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
+sys.path.insert(0, SRC)
+from repro.telemetry.accounting import V5E  # noqa: E402
 
 # one timing implementation: the measured rows drive the `bench` run kind
 # (Gym.bench) on a declarative run doc instead of a hand-rolled step loop
 _MEASURE = textwrap.dedent("""
     import os
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ndev}"
     import json, sys
     sys.path.insert(0, {src!r})
@@ -82,9 +85,9 @@ def measured(devices=(1, 2, 4, 8)):
 
 
 # -- analytic TPU model ------------------------------------------------------
-PEAK = 197e12
-HBM = 819e9
-BW = 50e9
+PEAK = V5E["flops_bf16"]
+HBM = V5E["hbm_bytes_s"]
+BW = V5E["ici_link_bytes_s"]
 ALPHA = 1e-6
 
 
@@ -93,13 +96,15 @@ def modeled_llama8b(unit_k: int = 1):
     batch 1024 x 4k tokens (strong scaling)."""
     import jax
 
-    sys.path.insert(0, SRC)
     from repro.configs import get_config
     from repro.models import build_model
 
     cfg = get_config("llama3_8b")
     model = build_model(cfg)
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    # shapes only: a concrete PRNG key would start a JAX backend in this
+    # parent, which must stay off the devices its children use
+    shapes = jax.eval_shape(model.init,
+                            jax.ShapeDtypeStruct((2,), jax.numpy.uint32))
     n_params = sum(math.prod(l.shape) for l in jax.tree_util.tree_leaves(shapes))
     stack = shapes["blocks"]
     layer_bytes = sum(math.prod(l.shape[1:]) * 2

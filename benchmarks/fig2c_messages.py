@@ -12,9 +12,13 @@ compiled dry-run HLO.
 import json
 import math
 import os
+import sys
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+from repro.telemetry.accounting import V5E  # noqa: E402
 
 ALPHA = 1e-6        # ICI per-hop launch latency (s) — order of magnitude
-BW = 50e9           # bytes/s per link
+BW = V5E["ici_link_bytes_s"]
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun")
 
 
@@ -39,9 +43,6 @@ def fsdp_unit_messages(arch: str = "llama3_8b"):
     """Per-layer FSDP all-gather bytes for unit sizes k=1..8: the framework's
     coalescing dial. Computed from the param shapes (what one scan step
     gathers), cross-checked against dry-run HLO messages where available."""
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
     import jax
 
     from repro.configs import get_config

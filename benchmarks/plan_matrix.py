@@ -2,7 +2,8 @@
 
 Runs ``Gym.bench`` (the one timing implementation) once per sharding
 plan on a forced-8-device CPU mesh and writes one row per plan —
-``steady_step_ms`` / ``mfu`` / ``tokens_per_s`` plus the analytic
+``steady_step_ms`` / ``tokens_per_s`` (and ``mfu`` on a device with a
+peak-table entry; never on the CPU) plus the analytic
 pipeline block (``pp``, ``n_micro``, ``bubble_fraction``) — into the
 tracked ``BENCH_plans.json`` at the repo root.  Absolute CPU numbers
 are meaningless as GPU/TPU predictors; the row set exists so every
@@ -78,7 +79,8 @@ def bench_plan(plan_name: str, arch: str, mesh_kw, steps: int, warmup: int,
         "mesh": "x".join(f"{k}={v}" for k, v in mesh_kw.items()),
         "n_layers": cfg.n_layers,
     }
-    for k in ("plan", "pipeline", "compile_s", "steady_step_ms",
+    for k in ("platform", "device_kind", "device_count",
+              "plan", "pipeline", "compile_s", "steady_step_ms",
               "steady_step_ms_mean", "mfu", "tokens_per_s", "final_loss",
               "global_batch", "seq_len"):
         if k in res:

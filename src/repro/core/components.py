@@ -260,11 +260,18 @@ def _bpe_tokenizer(path: str = "", corpus: str = "",
 
 def _synthetic_chunked(n_tokens: int, vocab: int, prefix: str, seq_len: int,
                        seed: int = 0, shuffle: bool = True):
+    """Seeded synthetic tokens under ``prefix``, generated once. The files
+    are keyed by everything that generates them, so a run never reads data
+    made for another vocabulary, size or seed at the same prefix."""
     import os
 
-    if not os.path.exists(prefix + ".tokens.u32"):
-        synthetic_dataset(n_tokens, vocab, prefix, seed)
-    return ChunkedLMDataset(PackedDataset(prefix), seq_len, seed, shuffle)
+    from ..data.packed_dataset import DOCIDX_SUFFIX
+
+    keyed = f"{prefix}.v{int(vocab)}.n{int(n_tokens)}.s{int(seed)}"
+    if not os.path.exists(keyed + DOCIDX_SUFFIX):   # written last
+        os.makedirs(os.path.dirname(keyed) or ".", exist_ok=True)
+        synthetic_dataset(n_tokens, vocab, keyed, seed)
+    return ChunkedLMDataset(PackedDataset(keyed), seq_len, seed, shuffle)
 
 
 class _StdoutTracker(IF.TrackerIF):

@@ -371,7 +371,7 @@ class Gym:
                                                  time.perf_counter(),
                                                  step=step)
                             if self.profiler is not None:
-                                self.profiler.step_end(step)
+                                self.profiler.step_end(step, metrics)
                             if inj is not None and \
                                     inj.fire("preempt", step) is not None:
                                 guard.request()
@@ -486,9 +486,9 @@ class Gym:
     def bench(self, steps: int = 20, warmup: int = 3,
               windows: int = 5) -> Dict[str, Any]:
         """Measure the hot path: compile time, steady-state step time,
-        tokens/sec, and modeled MFU. The ONE timing implementation behind
-        the ``bench`` run kind (``python -m repro bench``) and
-        ``benchmarks/``.
+        tokens/sec, and MFU on the device it ran on. The ONE timing
+        implementation behind the ``bench`` run kind (``python -m repro
+        bench``) and ``benchmarks/``.
 
         The ``steps`` are split into ``windows`` synchronized windows and
         ``steady_step_ms`` is the median of the per-window step times —
@@ -496,50 +496,79 @@ class Gym:
         container skew the whole figure (wall-clock swings of ~50% are
         documented in CHANGES.md); the median of several windows is
         robust to it.  Per-window rows ship in the result for
-        inspection.
+        inspection.  Steps are numbered from 1 (the compile step) for the
+        ``profiler`` window: place it in the warm-up steps to keep the
+        timed windows free of tracing cost.  ``losses`` holds every
+        step's loss, compile and warm-up steps included.
         """
         import statistics
+
+        from ..telemetry import accounting as ACC
 
         t0 = time.perf_counter()
         state = self.setup()
         setup_s = time.perf_counter() - t0
         start = int(state["step"])
+        n_params = ACC.count_param_leaves(state["params"])
         tel = self.telemetry
+        prof = self.profiler
         n_w = max(1, min(int(windows), steps))
         base, rem = divmod(steps, n_w)
         sizes = [base + (1 if w < rem else 0) for w in range(n_w)]
         sizes = [s for s in sizes if s > 0]
+        losses: List[Any] = []   # device scalars, fetched once at the end
         ctx = self.mesh if self.mesh is not None else _nullctx()
-        with ctx:
-            loader = self._wrapped_loader()
-            it = iter(loader.batches(1 + warmup + steps, start_step=start))
-            t0 = time.perf_counter()
-            state, m = self._step(state, next(it))
-            jax.block_until_ready(m)
-            compile_s = time.perf_counter() - t0  # first call: trace+compile+run
-            for _ in range(warmup):
-                state, m = self._step(state, next(it))
-            jax.block_until_ready(m)
-            window_rows: List[Dict[str, Any]] = []
-            for k in sizes:
-                tw0 = time.perf_counter()
-                for _ in range(k):
+        try:
+            with ctx:
+                loader = self._wrapped_loader()
+                it = iter(loader.batches(1 + warmup + steps,
+                                         start_step=start))
+
+                def step(state):
+                    n = start + len(losses) + 1
+                    if prof is not None:
+                        prof.step_begin(n)
                     state, m = self._step(state, next(it))
+                    losses.append(m.get("loss", m.get("ce")))
+                    if prof is not None:
+                        prof.step_end(n, m)
+                    return state, m
+
+                t0 = time.perf_counter()
+                state, m = step(state)
                 jax.block_until_ready(m)
-                tw1 = time.perf_counter()
-                window_rows.append({"steps": k, "wall_s": round(tw1 - tw0, 6),
-                                    "step_ms": round((tw1 - tw0) / k * 1000,
-                                                     3)})
-                if tel is not None:
-                    tel.metric(len(window_rows),
-                               {"bench_step_ms": window_rows[-1]["step_ms"],
-                                "bench_window_steps": k},
-                               phase="bench_window")
-            jax.block_until_ready(state["step"])
+                compile_s = time.perf_counter() - t0  # trace+compile+run
+                for _ in range(warmup):
+                    state, m = step(state)
+                jax.block_until_ready(m)
+                window_rows: List[Dict[str, Any]] = []
+                for k in sizes:
+                    tw0 = time.perf_counter()
+                    for _ in range(k):
+                        state, m = step(state)
+                    jax.block_until_ready(m)
+                    tw1 = time.perf_counter()
+                    window_rows.append({"steps": k,
+                                        "wall_s": round(tw1 - tw0, 6),
+                                        "step_ms": round((tw1 - tw0) / k
+                                                         * 1000, 3)})
+                    if tel is not None:
+                        tel.metric(len(window_rows),
+                                   {"bench_step_ms":
+                                    window_rows[-1]["step_ms"],
+                                    "bench_window_steps": k},
+                                   phase="bench_window")
+                jax.block_until_ready(state["step"])
+        finally:
+            if prof is not None:
+                prof.close()
         wall = sum(r["wall_s"] for r in window_rows)
         steady_ms = statistics.median(r["step_ms"] for r in window_rows)
-        loss = float(jax.device_get(m.get("loss", m.get("ce"))))
+        losses = [float(v) for v in jax.device_get(losses)]
+        devices = ACC.run_devices(self.mesh)
         result = {
+            **ACC.device_info(devices),
+            "n_params": n_params,
             "steps": steps,
             "warmup": warmup,
             "setup_s": round(setup_s, 3),
@@ -548,7 +577,8 @@ class Gym:
             "steady_step_ms_mean": round(wall / steps * 1000, 3),
             "windows": window_rows,
             "steps_per_s": round(steps / wall, 3) if wall > 0 else 0.0,
-            "final_loss": round(loss, 6),
+            "final_loss": round(losses[-1], 6),
+            "losses": losses,
             "prefetch": self.prefetch,
             "grad_accum": self.grad_accum,
             # a clean bench dispatches every step productively by
@@ -561,14 +591,28 @@ class Gym:
                                        "retry_count", 0) or 0),
             "graceful_exit": False,
         }
-        from ..telemetry import accounting as ACC
-
+        # where the params and optimizer state live: bytes each device holds
+        leaves = jax.tree_util.tree_leaves((state["params"], state["opt"]))
+        per_dev: Dict[int, int] = {}
+        for leaf in leaves:
+            for shard in leaf.addressable_shards:
+                per_dev[shard.device.id] = (per_dev.get(shard.device.id, 0)
+                                            + shard.data.nbytes)
+        result["state_bytes"] = sum(leaf.nbytes for leaf in leaves)
+        result["state_bytes_per_device"] = per_dev
+        stats = devices[0].memory_stats()
+        if stats and "peak_bytes_in_use" in stats:
+            result["peak_bytes_in_use"] = int(stats["peak_bytes_in_use"])
+        if prof is not None and prof.artifact:
+            result["profile_trace"] = prof.artifact
         flops = ACC.flops_per_train_step(self.model, self.loader,
                                          self.grad_accum)
-        n_dev = int(self.mesh.devices.size) if self.mesh is not None else 1
+        peaks = ACC.device_peaks(devices[0])
         if flops:
             result["model_flops_per_step"] = flops
-            result["mfu"] = ACC.mfu(flops, steady_ms / 1000.0, n_dev)
+            if peaks is not None:
+                result["mfu"] = ACC.mfu(flops, steady_ms / 1000.0,
+                                        len(devices), peaks["flops_bf16"])
         gb = getattr(self.loader, "global_batch", None)
         seq = getattr(getattr(self.loader, "dataset", None), "seq_len", None)
         if gb and seq:

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -65,8 +67,7 @@ def _flash_body(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 
 def flash_attention_bkg(q, k, v, *, causal: bool = True, window: int = 0,
-                        block_q: int = 128, block_kv: int = 128,
-                        interpret: bool = True):
+                        block_q: int = 128, block_kv: int = 128):
     """q [BKG, Sq, dh] grouped-flattened queries; k/v [BK, Skv, dh].
 
     BKG = batch · kv_heads · group_size; BK = batch · kv_heads. K/V tiles are
@@ -90,7 +91,7 @@ def flash_attention_bkg(q, k, v, *, causal: bool = True, window: int = 0,
         _flash_body, scale=scale, block_q=block_q, block_kv=block_kv,
         n_kv=nk, causal=causal, window=window, s_q=Sq, s_kv=Skv,
     )
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=(BKG, nq, nk),
         in_specs=[
@@ -105,6 +106,5 @@ def flash_attention_bkg(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        interpret=interpret,
     )(q, k, v)
     return out[:, :Sq]

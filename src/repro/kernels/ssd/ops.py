@@ -9,23 +9,23 @@ import functools
 import jax
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _ssd_vjp(x, dt, A, Bm, Cm, D_skip, chunk, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _ssd_vjp(x, dt, A, Bm, Cm, D_skip, chunk):
     from .kernel import ssd_scan
 
-    return ssd_scan(x, dt, A, Bm, Cm, D_skip, chunk=chunk, interpret=interpret)
+    return ssd_scan(x, dt, A, Bm, Cm, D_skip, chunk=chunk)
 
 
-def ssd(x, dt, A, Bm, Cm, D_skip, *, chunk: int = 128, interpret: bool = True):
-    return _ssd_vjp(x, dt, A, Bm, Cm, D_skip, chunk, interpret)
+def ssd(x, dt, A, Bm, Cm, D_skip, *, chunk: int = 128):
+    return _ssd_vjp(x, dt, A, Bm, Cm, D_skip, chunk)
 
 
-def _fwd(x, dt, A, Bm, Cm, D_skip, chunk, interpret):
-    out = _ssd_vjp(x, dt, A, Bm, Cm, D_skip, chunk, interpret)
+def _fwd(x, dt, A, Bm, Cm, D_skip, chunk):
+    out = _ssd_vjp(x, dt, A, Bm, Cm, D_skip, chunk)
     return out, (x, dt, A, Bm, Cm, D_skip)
 
 
-def _bwd(chunk, interpret, res, g):
+def _bwd(chunk, res, g):
     from ...models.ssm import ssd_chunked
 
     x, dt, A, Bm, Cm, D_skip = res

@@ -28,6 +28,7 @@ from ..configs.shapes import SHAPES
 from ..models import build_model
 from ..optim.adamw import AdamW
 from ..sharding import plans as PL
+from ..telemetry.accounting import V5E
 from ..train import steps as ST
 from . import mesh as MESH
 from . import specs as SP
@@ -171,8 +172,6 @@ def compile_run(cfg, shape, mesh, plan=None, *, grad_accum: int = 1,
     t_compile = time.time() - t0
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jaxlib returns [dict]
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
     except Exception:
@@ -202,9 +201,9 @@ def compile_run(cfg, shape, mesh, plan=None, *, grad_accum: int = 1,
         "model_flops_global": mflops,
         "n_params": n_total,
         "n_params_active": n_active,
-        "compute_term_s": flops_dev / MESH.PEAK_FLOPS_BF16,
-        "memory_term_s": bytes_dev / MESH.HBM_BW,
-        "collective_term_s": ana["collective_bytes"] / MESH.ICI_BW,
+        "compute_term_s": flops_dev / V5E["flops_bf16"],
+        "memory_term_s": bytes_dev / V5E["hbm_bytes_s"],
+        "collective_term_s": ana["collective_bytes"] / V5E["ici_link_bytes_s"],
         "lower_s": round(t_lower, 1),
         "compile_s": round(t_compile, 1),
         "sharding_warnings": warnings,

@@ -113,8 +113,3 @@ class SplitMesh(MeshProvider):
     def _make(self):
         return make_split_mesh(self.dp, self.tp)
 
-
-# Hardware constants: TPU v5e
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link (effective, one link)

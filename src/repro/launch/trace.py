@@ -17,7 +17,9 @@ import argparse
 import math
 import sys
 
-ALPHA, BW = 1e-6, 50e9
+from ..telemetry.accounting import V5E
+
+ALPHA, BW = 1e-6, V5E["ici_link_bytes_s"]
 
 
 def format_schedule(res, top: int = 20) -> str:

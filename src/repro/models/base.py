@@ -101,8 +101,9 @@ class ArchConfig:
     mtp: bool = False
     # MLA decode: absorb wkv_b into q/out sides (no per-step KV expansion)
     mla_absorb: bool = False
-    # route self-attention through the Pallas flash kernel
-    # (interpret=True off-TPU; pure-jnp paths otherwise)
+    # route self-attention through the Pallas flash kernel (compiled on
+    # TPU, interpreted on CPU — see repro.kernels.pallas_call); off, the
+    # pure-jnp attention paths run
     use_flash_kernel: bool = False
     # FSDP unit size: layers per scan step (all-gather message granularity)
     scan_block_size: int = 1

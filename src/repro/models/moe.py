@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from . import base as B
 from .common import dense_init
 
@@ -206,7 +205,7 @@ def moe_ep(cfg: B.ArchConfig, p, x_flat, idx, gate, mesh_ctx: B.MeshContext,
         storage_axes=storage_axes if storage_axes else (),
         ep_size=ep_size,
     )
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh_ctx.mesh,
         in_specs=(P(*dp, None), P(*dp, None), P(*dp, None)) + w_specs,

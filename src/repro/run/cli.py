@@ -29,6 +29,26 @@ from typing import List, Optional
 #: JAX initialises its platform (harmless for in-process gym runs).
 _FORCE_DEVICES_KINDS = ("dryrun", "trace", "sweep")
 _XLA_FLAGS = "--xla_force_host_platform_device_count=512"
+_CHECKOUT = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                         "..", "..", ".."))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is left
+    to JAX. Otherwise the cache is ``.jax_cache/`` at the checkout root: a
+    fixed path, so that the next process finds what this one compiled.
+    Call it before the first compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _add_kind_parser(sub, kind: str, help_text: str):
@@ -286,6 +306,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     command = args.command
     if command in _FORCE_DEVICES_KINDS:
         os.environ.setdefault("XLA_FLAGS", _XLA_FLAGS)
+    if command != "validate":
+        use_compile_cache()
 
     from ..config.resolver import ConfigError
     from ..sweep.spec import SweepError

@@ -116,7 +116,7 @@ def _build_profiler(ctx, s, recorder):
     from ..telemetry import ProfilerHook
 
     return ProfilerHook(p.start_step, p.num_steps, out_dir,
-                        recorder=recorder, log=ctx.log)
+                        recorder=recorder)
 
 
 # ---------------------------------------------------------------------------
@@ -358,16 +358,19 @@ def _drive_gym(ctx, s, gym, before_run=None) -> Dict[str, Any]:
                                    "retry_count", 0) or 0),
         "graceful_exit": bool(out.get("preempted", False)),
     }
+    devices = ACC.run_devices(getattr(gym, "mesh", None))
+    result.update(ACC.device_info(devices))
     if steps > 0 and wall > 0:
         flops = ACC.flops_per_train_step(getattr(gym, "model", None),
                                          getattr(gym, "loader", None),
                                          getattr(gym, "grad_accum", 1))
+        peaks = ACC.device_peaks(devices[0])
         if flops:
-            n_dev = int(gym.mesh.devices.size) \
-                if getattr(gym, "mesh", None) is not None else 1
             result["model_flops_per_step"] = flops
-            result["mfu"] = ACC.mfu(flops, wall / dispatched
-                                    if dispatched else wall / steps, n_dev)
+            if peaks is not None:
+                result["mfu"] = ACC.mfu(
+                    flops, wall / dispatched if dispatched else wall / steps,
+                    len(devices), peaks["flops_bf16"])
     plan = getattr(gym, "plan", None)
     if plan is not None and hasattr(plan, "describe"):
         from ..sharding import plans as PL
@@ -650,6 +653,9 @@ def execute_bench(ctx) -> Dict[str, Any]:
     rec = _build_telemetry(ctx, s)
     if rec is not None and hasattr(gym, "telemetry"):
         gym.telemetry = rec
+        prof = _build_profiler(ctx, s, rec)
+        if prof is not None and hasattr(gym, "profiler"):
+            gym.profiler = prof
         rec.event("run_start", steps=s.steps, warmup=s.warmup,
                   windows=s.windows)
     try:
@@ -758,10 +764,14 @@ def execute_serve(ctx) -> Dict[str, Any]:
             "entry is missing or builds no devices (single_device) — the "
             "run would silently serve unsharded; add a device mesh or drop "
             "the plan")
+    from ..telemetry import accounting as ACC
+
+    devices = ACC.device_info(ACC.run_devices(mesh))
     if not s.engine:
-        return serve_benchmark(model, batch=s.batch, prompt_len=s.prompt_len,
-                               gen=s.gen, ckpt=s.ckpt, seed=s.seed,
-                               mesh=mesh, plan=plan, log=ctx.log)
+        return {**serve_benchmark(model, batch=s.batch,
+                                  prompt_len=s.prompt_len, gen=s.gen,
+                                  ckpt=s.ckpt, seed=s.seed, mesh=mesh,
+                                  plan=plan, log=ctx.log), **devices}
 
     # -- continuous-batching engine path ------------------------------------
     from ..serve.engine import ServeEngine, load_params
@@ -816,6 +826,7 @@ def execute_serve(ctx) -> Dict[str, Any]:
             rec.close()
         raise
     result["arch"] = model.cfg.name
+    result.update(devices)
     # resilience fields per the BENCH_* schema (serving never rolls back
     # or checkpoints; a clean engine run reports zeros)
     result.setdefault("rollback_count", 0)

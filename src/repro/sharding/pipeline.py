@@ -36,8 +36,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 
 def gpipe_apply(
     stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -104,7 +102,7 @@ def gpipe_apply(
         )
         return outs
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(pipe_axis), P()),

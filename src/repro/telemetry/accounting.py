@@ -12,11 +12,11 @@ Definitions (documented in docs/observability.md):
     for MoE configs — the same numerator dryrun's
     ``useful_flops_ratio`` uses.
 ``mfu``
-    model FLOPs/step ÷ (measured step seconds × peak FLOP/s × devices).
-    Peak is the repo's modeled accelerator (``launch.mesh
-    .PEAK_FLOPS_BF16``, TPU v5e bf16); on CPU CI hosts the value is a
-    *modeled* utilization — tiny but nonzero, and comparable across
-    commits because numerator and denominator are both deterministic.
+    model FLOPs/step ÷ (measured step seconds × peak FLOP/s × devices),
+    with the peak taken from :data:`PEAKS` by the ``device_kind`` the run
+    measured on.  On the CPU no device metric exists, so ``mfu`` is not
+    written; an accelerator missing from the table is an error, never a
+    default.
 ``goodput``
     productive steps ÷ dispatched steps.  Rollback replays, anomaly
     skips, and steps discarded by preemption all dispatch work that
@@ -26,9 +26,50 @@ Definitions (documented in docs/observability.md):
 from __future__ import annotations
 
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
-from ..launch import mesh as MESH
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s inter-chip interconnect over four links.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_s": 819e9,
+                    "ici_link_bytes_s": 50e9},
+}
+#: The entry the dry-run roofline models (a described, not a measured chip).
+V5E = PEAKS["TPU v5 lite"]
+
+
+class UnknownDeviceError(ValueError):
+    """An accelerator with no entry in :data:`PEAKS`."""
+
+
+def device_peaks(device) -> Optional[Dict[str, float]]:
+    """The :data:`PEAKS` entry of ``device``. ``None`` on the CPU, where no
+    device metric is taken; an accelerator missing from the table raises
+    :class:`UnknownDeviceError`."""
+    if device.platform == "cpu":
+        return None
+    try:
+        return PEAKS[device.device_kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            f"no peak entry for {device.platform} device_kind "
+            f"{device.device_kind!r}; known: {sorted(PEAKS)}") from None
+
+
+def run_devices(mesh) -> list:
+    """The devices a run computes on: its mesh's, or the default device."""
+    import jax
+
+    return list(mesh.devices.flat) if mesh is not None else jax.devices()[:1]
+
+
+def device_info(devices) -> Dict[str, Any]:
+    """``platform``, ``device_kind`` and ``device_count`` of the devices a
+    result was measured on."""
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
 
 
 def count_param_leaves(params) -> int:
@@ -99,9 +140,9 @@ def flops_per_train_step(model, loader,
     return 6.0 * n_active * float(gb) * float(seq)
 
 
-def mfu(flops_per_step: float, step_s: float, n_devices: int = 1,
-        peak_flops: float = MESH.PEAK_FLOPS_BF16) -> float:
-    """Model-FLOPs utilization of the modeled accelerator fleet."""
+def mfu(flops_per_step: float, step_s: float, n_devices: int,
+        peak_flops: float) -> float:
+    """Model-FLOPs utilization of ``n_devices`` chips of ``peak_flops``."""
     if step_s <= 0 or n_devices <= 0 or peak_flops <= 0:
         return 0.0
     return flops_per_step / (step_s * peak_flops * n_devices)

@@ -8,9 +8,9 @@ Configured declaratively on the Run API:
 
 The hook is step-driven (``step_begin``/``step_end`` from the gym loop)
 so it composes with resume/warmstart: a run resumed past ``start_step``
-starts tracing at its first executed step at or beyond it.  Profiler
-failures (unsupported backend, missing tensorboard plugin) are recorded
-as an ``event`` row and never fail the run.
+starts tracing at its first executed step at or beyond it.  A configured
+window that cannot start or stop fails the run: a run asked for a trace
+and must not report success without one.
 """
 from __future__ import annotations
 
@@ -20,16 +20,14 @@ from typing import Optional
 
 class ProfilerHook:
     def __init__(self, start_step: int, num_steps: int, out_dir: str,
-                 recorder=None, log=None) -> None:
+                 recorder=None) -> None:
         self.start_step = max(1, int(start_step))
         self.num_steps = max(1, int(num_steps))
         self.out_dir = str(out_dir)
         self.recorder = recorder
-        self.log = log
         self.active = False
         self.done = False
         self.artifact: Optional[str] = None
-        self.error = ""
         self._stop_after = 0
 
     def step_begin(self, step: int) -> None:
@@ -37,27 +35,24 @@ class ProfilerHook:
             return
         import jax
 
-        try:
-            os.makedirs(self.out_dir, exist_ok=True)
-            jax.profiler.start_trace(self.out_dir)
-        except Exception as e:  # backend without profiler support
-            self.done = True
-            self.error = f"{type(e).__name__}: {e}"
-            if self.recorder is not None:
-                self.recorder.event("profile_error", step=step,
-                                    error=self.error)
-            if self.log:
-                self.log(f"[telemetry] profiler unavailable: {self.error}")
-            return
+        os.makedirs(self.out_dir, exist_ok=True)
+        jax.profiler.start_trace(self.out_dir)
         self.active = True
         self._stop_after = step + self.num_steps - 1
         if self.recorder is not None:
             self.recorder.event("profile_start", step=step,
                                 path=self.out_dir)
 
-    def step_end(self, step: int) -> None:
+    def step_end(self, step: int, outputs=None) -> None:
+        """Close the window after its last step. ``outputs`` (that step's
+        results) are waited for first, so the window's device work is in
+        the trace and not cut off by the asynchronous dispatch."""
         if not self.active or step < self._stop_after:
             return
+        if outputs is not None:
+            import jax
+
+            jax.block_until_ready(outputs)
         self._stop()
         if self.recorder is not None:
             self.recorder.event("profile_stop", step=step,
@@ -71,10 +66,7 @@ class ProfilerHook:
     def _stop(self) -> None:
         import jax
 
-        try:
-            jax.profiler.stop_trace()
-            self.artifact = self.out_dir
-        except Exception as e:
-            self.error = f"{type(e).__name__}: {e}"
         self.active = False
         self.done = True
+        jax.profiler.stop_trace()
+        self.artifact = self.out_dir

@@ -127,3 +127,23 @@ def test_sharded_loader_disjoint_deterministic():
     # deterministic across re-iteration
     again = next(iter(ranks[0].batches(1)))
     np.testing.assert_array_equal(batches[0]["tokens"], again["tokens"])
+
+
+def test_synthetic_dataset_files_keyed_by_vocab_size_and_seed(tmp_path):
+    """Two runs at one prefix never share data generated for the other's
+    vocabulary, size or seed (a reused 512-id file would feed a full-vocab
+    run; the other way round would gather out of range)."""
+    import repro.core.components  # noqa: F401  (populates the registry)
+    from repro.config.registry import DEFAULT_REGISTRY as REG
+
+    prefix = str(tmp_path / "nested" / "syn")
+    build = lambda **kw: REG.build("dataset", "synthetic", prefix=prefix,  # noqa: E731
+                                   seq_len=8, **kw)
+    small = build(n_tokens=5000, vocab=16)
+    big = build(n_tokens=5000, vocab=100_000)
+    assert int(np.max(small.dataset.tokens)) < 16
+    assert int(np.max(big.dataset.tokens)) >= 16
+    other_seed = build(n_tokens=5000, vocab=16, seed=1)
+    assert not np.array_equal(small.dataset.tokens, other_seed.dataset.tokens)
+    again = build(n_tokens=5000, vocab=16)
+    assert np.array_equal(small.dataset.tokens, again.dataset.tokens)

@@ -405,6 +405,23 @@ def test_cli_rejects_kind_mismatch(tmp_path, capsys):
     assert "launched as" in capsys.readouterr().err
 
 
+def test_compile_cache_dir_from_env_or_checkout(monkeypatch):
+    import jax
+
+    from repro.run import cli
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda key, value: calls.append((key, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax")
+    assert cli.use_compile_cache() == "/elsewhere/jax"
+    assert calls == []           # JAX reads its own variable; no code path
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = cli.use_compile_cache()
+    assert path == os.path.join(os.path.abspath(ROOT), ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", path)]
+
+
 # ---------------------------------------------------------------------------
 # normalized mesh providers (satellite)
 # ---------------------------------------------------------------------------

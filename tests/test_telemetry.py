@@ -201,7 +201,30 @@ def test_mfu_known_flops_arithmetic():
     # 1e12 FLOPs in 0.5s on 2 devices of 1e12 peak -> 1e12/(0.5*2e12) = 1.0
     assert ACC.mfu(1e12, 0.5, 2, peak_flops=1e12) == pytest.approx(1.0)
     assert ACC.mfu(1e12, 1.0, 1, peak_flops=4e12) == pytest.approx(0.25)
-    assert ACC.mfu(1e12, 0.0, 1) == 0.0
+    assert ACC.mfu(1e12, 0.0, 1, peak_flops=1e12) == 0.0
+
+
+class _Device:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_peak_table_lookup_by_device_kind():
+    v5e = ACC.device_peaks(_Device("tpu", "TPU v5 lite"))
+    assert v5e is ACC.V5E
+    assert v5e["flops_bf16"] == 197e12 and v5e["hbm_bytes_s"] == 819e9
+    # the CPU takes no device metric: no entry, and no error
+    assert ACC.device_peaks(_Device("cpu", "cpu")) is None
+    # this suite runs on the CPU
+    assert ACC.device_peaks(ACC.run_devices(None)[0]) is None
+    # an accelerator missing from the table is an error, never a default
+    with pytest.raises(ACC.UnknownDeviceError, match="TPU v9"):
+        ACC.device_peaks(_Device("tpu", "TPU v9"))
+    with pytest.raises(ACC.UnknownDeviceError):
+        ACC.device_peaks(_Device("gpu", "NVIDIA H100"))
+    info = ACC.device_info([_Device("tpu", "TPU v5 lite")] * 4)
+    assert info == {"platform": "tpu", "device_kind": "TPU v5 lite",
+                    "device_count": 4}
 
 
 def test_goodput_clamped_ratio():
@@ -319,7 +342,9 @@ def test_train_run_emits_schema_valid_telemetry(tmp_path):
     # MFU/goodput land in the result
     assert result["goodput"] == 1.0
     assert result["steps_dispatched"] == 4
-    assert 0 < result["mfu"] < 1
+    # a CPU run measures no device metric: mfu is not written at all
+    assert "mfu" not in result and result["model_flops_per_step"] > 0
+    assert result["platform"] == "cpu" and result["device_count"] == 1
 
 
 def test_telemetry_off_no_file_and_bitwise_identical_curves(tmp_path):
@@ -385,11 +410,21 @@ def test_profiler_hook_records_trace(tmp_path):
                                          "num_steps": 1}}}))
     rows = read_jsonl(result["telemetry"]["file"], validate=True)
     names = [r["name"] for r in rows if r["type"] == "event"]
-    if "profile_error" in names:          # platform without profiler support
-        assert "profile_trace" not in result
-    else:
-        assert "profile_start" in names and "profile_stop" in names
-        assert os.path.isdir(result["profile_trace"])
+    assert "profile_start" in names and "profile_stop" in names
+    assert os.path.isdir(result["profile_trace"])
+
+
+def test_profiler_window_that_cannot_start_fails_the_run(tmp_path,
+                                                         monkeypatch):
+    def refuse(*a, **k):
+        raise RuntimeError("no profiler on this backend")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="no profiler"):
+        run_api.execute_doc(_train_doc(
+            tmp_path, "prof_fail", steps=3,
+            train={"telemetry": {"profile": {"start_step": 2,
+                                             "num_steps": 1}}}))
 
 
 def test_csv_sink_through_run(tmp_path):
