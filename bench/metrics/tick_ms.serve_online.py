@@ -1,0 +1,9 @@
+"""tick_ms.serve_online: the engine's mean decode tick, its own ``decode_s`` over
+``ticks`` (dispatch to the tokens back on the host), net of the
+profiler's start and stop in a traced run."""
+
+
+def read(record):
+    if record.get("job") != "serve_online" or not record.get("ticks"):
+        return None
+    return 1000.0 * record["decode_s"] / record["ticks"]

@@ -1,0 +1,56 @@
+"""Weights from a seed: one jitted call on the device, float32.
+
+The program and the references are both given these weights, made from the
+same seed by the same function, so the reference never takes anything the
+program made. The names and shapes come from the reference family's
+``param_specs``; the program's adapter only rearranges the arrays.
+"""
+from __future__ import annotations
+
+import zlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def seed_key(seed: int, stream: str):
+    """A PRNG key from any non-negative integer seed (wider than 32 bits is
+    fine) and a stream label, so that weights, data and traffic of one seed
+    are independent draws."""
+    words = np.random.SeedSequence(
+        [int(seed), zlib.crc32(stream.encode())]).generate_state(2)
+    return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32),
+                                    impl="threefry2x32")
+
+
+def _draw(key, shape, init):
+    kind = init[0]
+    if kind == "normal":
+        return jax.random.normal(key, shape, jnp.float32) * init[1]
+    if kind == "around_one":
+        return 1.0 + jax.random.normal(key, shape, jnp.float32) * init[1]
+    if kind == "uniform":
+        return jax.random.uniform(key, shape, jnp.float32, init[1], init[2])
+    if kind == "log_uniform":
+        lo, hi = np.log(init[1]), np.log(init[2])
+        return jax.random.uniform(key, shape, jnp.float32, lo, hi)
+    if kind == "softplus_inverse_log_uniform":
+        lo, hi = np.log(init[1]), np.log(init[2])
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, lo, hi))
+        return dt + jnp.log(-jnp.expm1(-dt))        # softplus^-1(dt)
+    raise ValueError(f"unknown init {init!r}")
+
+
+def make(specs: dict, seed: int) -> dict:
+    """name -> float32 array on the default device, for ``specs`` of
+    ``name -> (shape, init)``. A ``log_uniform`` init is drawn as the log of
+    the value (the parameter is stored as a logarithm)."""
+    names = sorted(specs)
+
+    @jax.jit
+    def gen(key):
+        return {n: _draw(jax.random.fold_in(key, i), tuple(specs[n][0]),
+                         specs[n][1]) for i, n in enumerate(names)}
+
+    return gen(seed_key(seed, "weights"))
