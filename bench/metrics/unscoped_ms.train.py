@@ -1,0 +1,12 @@
+"""unscoped_ms.train: device self-time of the ops under no layer scope
+(copies included) per ``gym/step`` span of the traced window, in ms; with
+the other four ``*_ms.train`` metrics it partitions the device's busy time
+(``bench/trace_scopes.py``)."""
+from bench import trace_scopes
+
+
+def read(record):
+    if record.get("job") != "train":
+        return None
+    return trace_scopes.train_ms_per_step(
+        trace_scopes.trace_of(record, __file__), "unscoped")

@@ -224,7 +224,7 @@ def serve_phase():
         log(f"serve (prefix_cache={cache}): {res['completed']} requests "
             f"done, hit rate {res['prefill_cache_hit_rate']}, compile "
             f"{res['compile_s']} s, ttft p50 {res['ttft_s'].get('p50')} s, "
-            f"tpot p50 {res['tpot_ms'].get('p50')} ms (smoke observation)")
+            f"tick p50 {res['tick_ms'].get('p50')} ms (smoke observation)")
     check(streams[True] == streams[False],
           "token streams differ between prefix cache on and off")
     log("serve: token streams identical with prefix cache on and off")

@@ -33,6 +33,7 @@ import jax
 
 from ..data.prefetch import PrefetchLoader
 from ..sharding import plans as PL
+from ..telemetry.spans import span
 from ..train import checkpoint as CK
 from ..train import steps as ST
 
@@ -251,7 +252,6 @@ class Gym:
         data_offset = 0  # grows when skip_window drops anomalous batches
         t_run0 = time.perf_counter()  # full-precision monotonic epoch
         tel = self.telemetry
-        do_spans = tel is not None and tel.spans
         inj = self.fault_injector
         guard = self.preempt_guard
         if guard is None and inj is not None and inj.pending("preempt"):
@@ -276,33 +276,29 @@ class Gym:
                     def flush(pending=pending):
                         if not pending:
                             return
-                        t_f0 = time.perf_counter()
-                        last_step = pending[-1][0]
-                        fetched = jax.device_get([m for _, m, _ in pending])
-                        rows = list(zip(list(pending), fetched))
-                        pending.clear()
-                        for (step, _, wall), vals in rows:
-                            m = {k: float(v) for k, v in vals.items()}
-                            if inj is not None and \
-                                    inj.fire("nan_loss", step) is not None:
-                                m["loss"] = float("nan")
-                            m["step"] = step
-                            m["wall_s"] = wall
-                            if tel is not None:
-                                # telemetry sees the observation even when
-                                # the sentinel is about to trip on it
-                                tel.metric(step, {k: v for k, v in m.items()
-                                                  if k != "step"})
-                            if self.sentinel is not None:
-                                anomaly = self.sentinel.check(step, m)
-                                if anomaly is not None:
-                                    raise _Rollback(anomaly)
-                            history.append(m)
-                            if self.logger:
-                                self.logger(m)
-                        if do_spans:
-                            tel.span_row("gym/flush", t_f0,
-                                         time.perf_counter(), step=last_step)
+                        with span("gym/flush", tel, step=pending[-1][0]):
+                            fetched = jax.device_get([m for _, m, _ in pending])
+                            rows = list(zip(list(pending), fetched))
+                            pending.clear()
+                            for (step, _, wall), vals in rows:
+                                m = {k: float(v) for k, v in vals.items()}
+                                if inj is not None and \
+                                        inj.fire("nan_loss", step) is not None:
+                                    m["loss"] = float("nan")
+                                m["step"] = step
+                                m["wall_s"] = wall
+                                if tel is not None:
+                                    # telemetry sees the observation even
+                                    # when the sentinel is about to trip on it
+                                    tel.metric(step, {k: v for k, v in m.items()
+                                                      if k != "step"})
+                                if self.sentinel is not None:
+                                    anomaly = self.sentinel.check(step, m)
+                                    if anomaly is not None:
+                                        raise _Rollback(anomaly)
+                                history.append(m)
+                                if self.logger:
+                                    self.logger(m)
 
                     loader = self._wrapped_loader()
                     batches = loader.batches(target - current,
@@ -310,31 +306,24 @@ class Gym:
                     stop_step = 0
                     try:
                         it = iter(batches)
-                        i = 0
-                        while True:
+                        for i in range(target - current):
                             # manual next() so the host-side wait for data
                             # is its own span, separated from dispatch
-                            t_wait0 = time.perf_counter()
-                            try:
-                                batch = next(it)
-                            except StopIteration:
-                                break
-                            t_wait1 = time.perf_counter()
                             step = current + i + 1
-                            i += 1
+                            with span("gym/data_wait", tel, step=step):
+                                batch = next(it, None)
+                            if batch is None:
+                                break
                             if self.profiler is not None:
+                                # before the step's span, which the trace
+                                # then holds from the window's first step
                                 self.profiler.step_begin(step)
-                            if inj is not None and \
-                                    inj.fire("nan_params", step) is not None:
-                                state = inj.corrupt_params(state)
-                            state, metrics = self._step(state, batch)
+                            with span("gym/step", tel, step=step):
+                                if inj is not None and \
+                                        inj.fire("nan_params", step) is not None:
+                                    state = inj.corrupt_params(state)
+                                state, metrics = self._step(state, batch)
                             dispatched += 1
-                            if do_spans:
-                                t_disp = time.perf_counter()
-                                tel.span_row("gym/data_wait", t_wait0,
-                                             t_wait1, step=step)
-                                tel.span_row("gym/step", t_wait1, t_disp,
-                                             step=step)
                             if self.log_every and (step % self.log_every == 0
                                                    or step == start + 1):
                                 # fetch the PREVIOUS window now (long since
@@ -363,13 +352,9 @@ class Gym:
                                 # snapshot completes before the next step can
                                 # donate the state buffers; serialization
                                 # runs on the writer thread
-                                t_ck0 = time.perf_counter()
-                                ckpt.save(state, step,
-                                          extra=self._ckpt_extra())
-                                if do_spans:
-                                    tel.span_row("gym/ckpt", t_ck0,
-                                                 time.perf_counter(),
-                                                 step=step)
+                                with span("gym/ckpt", tel, step=step):
+                                    ckpt.save(state, step,
+                                              extra=self._ckpt_extra())
                             if self.profiler is not None:
                                 self.profiler.step_end(step, metrics)
                             if inj is not None and \

@@ -75,7 +75,7 @@ def serve_benchmark(model, *, batch: int = 4, prompt_len: int = 32,
         "decode_steps": G - 1,
         "decode_tokens": out["decode_tokens"],
         "decode_tok_s": out["decode_tok_s"],
-        "tpot_ms": out["tpot_ms"],
+        "tick_ms": out["tick_ms"],
         "gen_tokens_total": out["generated_tokens"],
         "generated_ids": [r["gen_ids"] for r in rows],
         "generated_ids_0": rows[0]["gen_ids"] if rows else [],

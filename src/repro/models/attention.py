@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from . import base as B
 from .common import apply_rope, dense_init, rmsnorm
+from ..telemetry.spans import KV_READ, KV_WRITE, scope
 
 _BLOCKWISE_AT = 4096     # use blockwise path for S strictly above this
 # (<=4k trains through the plain einsum path — differentiable without
@@ -389,16 +390,18 @@ def mla_init_cache(cfg: B.ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat
 def paged_view(leaf, pages):
     """Gather ``leaf [n_blocks, bl, ...]`` through ``pages [..., n_pages]``
     into a contiguous view ``[..., n_pages * bl, ...]``."""
-    v = leaf[pages]
-    lead = pages.shape[:-1]
-    return v.reshape(lead + (pages.shape[-1] * leaf.shape[1],)
-                     + leaf.shape[2:])
+    with scope(KV_READ):
+        v = leaf[pages]
+        lead = pages.shape[:-1]
+        return v.reshape(lead + (pages.shape[-1] * leaf.shape[1],)
+                         + leaf.shape[2:])
 
 
 def _paged_write(leaf, phys, off, vals):
     """Scatter ``vals [N, ...]`` rows into ``leaf[phys[i], off[i]]``
     (``phys == n_blocks`` drops the write)."""
-    return leaf.at[phys, off].set(vals.astype(leaf.dtype))
+    with scope(KV_WRITE):
+        return leaf.at[phys, off].set(vals.astype(leaf.dtype))
 
 
 def gqa_init_paged_cache(cfg: B.ArchConfig, n_blocks: int, block_len: int,

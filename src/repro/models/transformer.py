@@ -25,6 +25,7 @@ from . import moe as MOE
 from . import ssm as S
 from . import stacked as ST
 from .common import apply_norm, embed_init, norm_axes, norm_params, softmax_cross_entropy, sharded_cross_entropy
+from ..telemetry.spans import ATTENTION, EMBED, HEAD, MLP, SSM, scope
 
 
 # ---------------------------------------------------------------------------
@@ -88,84 +89,89 @@ def ssm_block_axes(cfg: B.ArchConfig):
     return {"norm": norm_axes(cfg), "ssm": S.ssm_axes(cfg)}
 
 
+def _mlp_residual(cfg, kind, p, x, mesh_ctx=None, storage_axes=()):
+    """``x`` plus the MLP (or MoE) sub-block of its norm; returns (x, aux)."""
+    with scope(MLP):
+        h = apply_norm(cfg, p["mlp_norm"], x)
+        if kind == "moe_block":
+            h, aux = MOE.moe_forward(cfg, p["moe"], h, mesh_ctx, storage_axes)
+        else:
+            h = M.mlp_forward(cfg, p["mlp"], h)
+            aux = jnp.zeros((), jnp.float32)
+        return x + h, aux
+
+
 def apply_block(cfg, kind, p, x, positions, mesh_ctx, storage_axes=()):
     """Residual block; returns (x, aux)."""
     x = B.constrain(x, mesh_ctx)
-    aux = jnp.zeros((), jnp.float32)
     if kind == "ssm":
-        x = x + S.ssm_forward(cfg, p["ssm"], apply_norm(cfg, p["norm"], x))
-        return x, aux
-    h = apply_norm(cfg, p["attn_norm"], x)
-    if cfg.mla:
-        h = A.mla_forward(cfg, p["attn"], h, positions)
-    else:
-        h = A.gqa_forward(cfg, p["attn"], h, positions)
-    x = x + h
-    h = apply_norm(cfg, p["mlp_norm"], x)
-    if kind == "moe_block":
-        h, aux = MOE.moe_forward(cfg, p["moe"], h, mesh_ctx, storage_axes)
-    else:
-        h = M.mlp_forward(cfg, p["mlp"], h)
-    return B.constrain(x + h, mesh_ctx), aux
+        with scope(SSM):
+            x = x + S.ssm_forward(cfg, p["ssm"],
+                                  apply_norm(cfg, p["norm"], x))
+        return x, jnp.zeros((), jnp.float32)
+    with scope(ATTENTION):
+        h = apply_norm(cfg, p["attn_norm"], x)
+        if cfg.mla:
+            h = A.mla_forward(cfg, p["attn"], h, positions)
+        else:
+            h = A.gqa_forward(cfg, p["attn"], h, positions)
+        x = x + h
+    x, aux = _mlp_residual(cfg, kind, p, x, mesh_ctx, storage_axes)
+    return B.constrain(x, mesh_ctx), aux
 
 
 def decode_block(cfg, kind, p, cache, x, positions, mesh_ctx=None,
                  storage_axes=()):
     if kind == "ssm":
-        h, new_cache = S.ssm_decode(cfg, p["ssm"], cache, apply_norm(cfg, p["norm"], x))
-        return x + h, new_cache, jnp.zeros((), jnp.float32)
-    h = apply_norm(cfg, p["attn_norm"], x)
-    if cfg.mla:
-        h, new_cache = A.mla_decode(cfg, p["attn"], cache, h, positions,
-                                    absorb=cfg.mla_absorb)
-    else:
-        h, new_cache = A.gqa_decode(cfg, p["attn"], cache, h, positions)
-    x = x + h
-    h = apply_norm(cfg, p["mlp_norm"], x)
-    if kind == "moe_block":
-        h, _ = MOE.moe_forward(cfg, p["moe"], h, mesh_ctx, storage_axes)
-    else:
-        h = M.mlp_forward(cfg, p["mlp"], h)
-    return x + h, new_cache, jnp.zeros((), jnp.float32)
+        with scope(SSM):
+            h, new_cache = S.ssm_decode(cfg, p["ssm"], cache,
+                                        apply_norm(cfg, p["norm"], x))
+            x = x + h
+        return x, new_cache, jnp.zeros((), jnp.float32)
+    with scope(ATTENTION):
+        h = apply_norm(cfg, p["attn_norm"], x)
+        if cfg.mla:
+            h, new_cache = A.mla_decode(cfg, p["attn"], cache, h, positions,
+                                        absorb=cfg.mla_absorb)
+        else:
+            h, new_cache = A.gqa_decode(cfg, p["attn"], cache, h, positions)
+        x = x + h
+    x, _ = _mlp_residual(cfg, kind, p, x, mesh_ctx, storage_axes)
+    return x, new_cache, jnp.zeros((), jnp.float32)
 
 
 def decode_block_paged(cfg, kind, p, cache, x, positions, pages, active,
                        mesh_ctx=None, storage_axes=()):
     """``decode_block`` reading/writing K/V through page tables."""
-    h = apply_norm(cfg, p["attn_norm"], x)
-    if cfg.mla:
-        h, new_cache = A.mla_decode_paged(cfg, p["attn"], cache, h, positions,
-                                          pages, active, absorb=cfg.mla_absorb)
-    else:
-        h, new_cache = A.gqa_decode_paged(cfg, p["attn"], cache, h, positions,
-                                          pages, active)
-    x = x + h
-    h = apply_norm(cfg, p["mlp_norm"], x)
-    if kind == "moe_block":
-        h, _ = MOE.moe_forward(cfg, p["moe"], h, mesh_ctx, storage_axes)
-    else:
-        h = M.mlp_forward(cfg, p["mlp"], h)
-    return x + h, new_cache
+    with scope(ATTENTION):
+        h = apply_norm(cfg, p["attn_norm"], x)
+        if cfg.mla:
+            h, new_cache = A.mla_decode_paged(cfg, p["attn"], cache, h,
+                                              positions, pages, active,
+                                              absorb=cfg.mla_absorb)
+        else:
+            h, new_cache = A.gqa_decode_paged(cfg, p["attn"], cache, h,
+                                              positions, pages, active)
+        x = x + h
+    x, _ = _mlp_residual(cfg, kind, p, x, mesh_ctx, storage_axes)
+    return x, new_cache
 
 
 def prefill_chunk_block(cfg, kind, p, cache, x, positions, pages_row, n_valid,
                         mesh_ctx=None, storage_axes=()):
     """One layer of the fixed-shape chunked-prefill program."""
     x = B.constrain(x, mesh_ctx)
-    h = apply_norm(cfg, p["attn_norm"], x)
-    if cfg.mla:
-        h, new_cache = A.mla_prefill_chunk(cfg, p["attn"], cache, h, positions,
-                                           pages_row, n_valid)
-    else:
-        h, new_cache = A.gqa_prefill_chunk(cfg, p["attn"], cache, h, positions,
-                                           pages_row, n_valid)
-    x = x + h
-    h = apply_norm(cfg, p["mlp_norm"], x)
-    if kind == "moe_block":
-        h, _ = MOE.moe_forward(cfg, p["moe"], h, mesh_ctx, storage_axes)
-    else:
-        h = M.mlp_forward(cfg, p["mlp"], h)
-    return B.constrain(x + h, mesh_ctx), new_cache
+    with scope(ATTENTION):
+        h = apply_norm(cfg, p["attn_norm"], x)
+        if cfg.mla:
+            h, new_cache = A.mla_prefill_chunk(cfg, p["attn"], cache, h,
+                                               positions, pages_row, n_valid)
+        else:
+            h, new_cache = A.gqa_prefill_chunk(cfg, p["attn"], cache, h,
+                                               positions, pages_row, n_valid)
+        x = x + h
+    x, _ = _mlp_residual(cfg, kind, p, x, mesh_ctx, storage_axes)
+    return B.constrain(x, mesh_ctx), new_cache
 
 
 def _pad_cache_seq(k, max_len, window):
@@ -192,30 +198,33 @@ def prefill_block(cfg, kind, p, x, positions, max_len, cache_dtype, mesh_ctx=Non
     """Like apply_block but also returns the decode-ready cache leaf."""
     x = B.constrain(x, mesh_ctx)
     if kind == "ssm":
-        h, st = S.ssm_forward(cfg, p["ssm"], apply_norm(cfg, p["norm"], x),
-                              return_state=True)
-        return x + h, st
-    h = apply_norm(cfg, p["attn_norm"], x)
-    if cfg.mla:
-        h, (c_kv, k_rope) = A.mla_forward(cfg, p["attn"], h, positions,
-                                          return_latent=True)
-        cache = {
-            "c_kv": _pad_cache_seq(c_kv.astype(cache_dtype), max_len, 0),
-            "k_rope": _pad_cache_seq(k_rope.astype(cache_dtype), max_len, 0),
-        }
-    else:
-        h, (k, v) = A.gqa_forward(cfg, p["attn"], h, positions, return_kv=True)
-        cache = {
-            "k": _pad_cache_seq(k.astype(cache_dtype), max_len, cfg.window),
-            "v": _pad_cache_seq(v.astype(cache_dtype), max_len, cfg.window),
-        }
-    x = x + h
-    h = apply_norm(cfg, p["mlp_norm"], x)
-    if kind == "moe_block":
-        h, _ = MOE.moe_forward(cfg, p["moe"], h, mesh_ctx, storage_axes)
-    else:
-        h = M.mlp_forward(cfg, p["mlp"], h)
-    return B.constrain(x + h, mesh_ctx), cache
+        with scope(SSM):
+            h, st = S.ssm_forward(cfg, p["ssm"], apply_norm(cfg, p["norm"], x),
+                                  return_state=True)
+            x = x + h
+        return x, st
+    with scope(ATTENTION):
+        h = apply_norm(cfg, p["attn_norm"], x)
+        if cfg.mla:
+            h, (c_kv, k_rope) = A.mla_forward(cfg, p["attn"], h, positions,
+                                              return_latent=True)
+            cache = {
+                "c_kv": _pad_cache_seq(c_kv.astype(cache_dtype), max_len, 0),
+                "k_rope": _pad_cache_seq(k_rope.astype(cache_dtype), max_len,
+                                         0),
+            }
+        else:
+            h, (k, v) = A.gqa_forward(cfg, p["attn"], h, positions,
+                                      return_kv=True)
+            cache = {
+                "k": _pad_cache_seq(k.astype(cache_dtype), max_len,
+                                    cfg.window),
+                "v": _pad_cache_seq(v.astype(cache_dtype), max_len,
+                                    cfg.window),
+            }
+        x = x + h
+    x, _ = _mlp_residual(cfg, kind, p, x, mesh_ctx, storage_axes)
+    return B.constrain(x, mesh_ctx), cache
 
 
 def init_cache_block(cfg, kind, batch, max_len, dtype):
@@ -421,15 +430,17 @@ class DecoderLM(B.Model):
 
     def logits(self, params, x, mesh_ctx=None):
         cfg = self.cfg
-        x = apply_norm(cfg, params["final_norm"], x)
-        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        out = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
-        if mesh_ctx is not None and mesh_ctx.tp_axis is not None:
-            out = B.constrain(out, mesh_ctx, None, mesh_ctx.tp_axis)
+        with scope(HEAD):
+            x = apply_norm(cfg, params["final_norm"], x)
+            w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+            out = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
+            if mesh_ctx is not None and mesh_ctx.tp_axis is not None:
+                out = B.constrain(out, mesh_ctx, None, mesh_ctx.tp_axis)
         return out
 
     def embed_tokens(self, params, tokens, dtype=jnp.bfloat16):
-        return params["embed"].astype(dtype)[tokens]
+        with scope(EMBED):
+            return params["embed"].astype(dtype)[tokens]
 
     def apply(self, params, batch, mesh_ctx=None, storage_axes=()):
         cfg = self.cfg
@@ -521,16 +532,8 @@ class DecoderLM(B.Model):
                 x, c = prefill_block(cfg, "ssm", _take_layer(sp, i), x, positions,
                                      max_len, cache_dtype)
                 scs.append(c)
-            h = apply_norm(cfg, params["shared_attn"]["attn_norm"], x)
-            h, (k, v) = A.gqa_forward(cfg, params["shared_attn"]["attn"], h,
-                                      positions, return_kv=True)
-            ac = {
-                "k": _pad_cache_seq(k.astype(cache_dtype), max_len, cfg.window),
-                "v": _pad_cache_seq(v.astype(cache_dtype), max_len, cfg.window),
-            }
-            x = x + h
-            h = apply_norm(cfg, params["shared_attn"]["mlp_norm"], x)
-            x = x + M.mlp_forward(cfg, params["shared_attn"]["mlp"], h)
+            x, ac = prefill_block(cfg, "dense_block", params["shared_attn"],
+                                  x, positions, max_len, cache_dtype)
             stacked = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *scs)
             return x, (stacked, ac)
 
@@ -669,11 +672,8 @@ class DecoderLM(B.Model):
                 x, nc, _ = decode_block(cfg, "ssm", _take_layer(sp, i),
                                         _take_layer(sc, i), x, positions)
                 ncs.append(nc)
-            h = apply_norm(cfg, params["shared_attn"]["attn_norm"], x)
-            h, nac = A.gqa_decode(cfg, params["shared_attn"]["attn"], ac, h, positions)
-            x = x + h
-            h = apply_norm(cfg, params["shared_attn"]["mlp_norm"], x)
-            x = x + M.mlp_forward(cfg, params["shared_attn"]["mlp"], h)
+            x, nac, _ = decode_block(cfg, "dense_block", params["shared_attn"],
+                                     ac, x, positions)
             stacked = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ncs)
             return x, (stacked, nac)
 

@@ -39,13 +39,17 @@ def use_compile_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is left
     to JAX. Otherwise the cache is ``.jax_cache/`` at the checkout root: a
     fixed path, so that the next process finds what this one compiled.
+    The key includes the programs' metadata: without it a program compiled
+    from other sources (other layer scopes, or none) with the same
+    operations is a hit, and its traces show that build's names.
     Call it before the first compile.
     """
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
-
     path = os.path.join(_CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

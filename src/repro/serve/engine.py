@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..sharding import plans as PL
+from ..telemetry.spans import SAMPLE, scope, span
 from ..train import steps as ST
 from .paging import BlockAllocator, RadixPrefixIndex
 from .sampling import request_key, sample_tokens
@@ -236,6 +237,7 @@ class ServeEngine:
         same head the dense admit fuses into ``prefill_into``)."""
         greedy = self.greedy
 
+        @scope(SAMPLE)
         def first_token(logits, key, temperature, top_k, top_p):
             if greedy:
                 return jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
@@ -356,8 +358,9 @@ class ServeEngine:
 
         ``realtime=False`` ignores arrival offsets (closed loop, maximum
         pressure — the bench mode).  Metrics: TTFT (arrival -> first token,
-        queueing included; split hit/cold in paged mode), per-decode-token
-        latency percentiles, tokens/s, slot utilization, and — paged —
+        queueing included; split hit/cold in paged mode), decode-tick time
+        percentiles (``tick_ms``, each tick once), tokens/s, slot
+        utilization, and — paged —
         prefix-cache hit rate plus allocator/eviction counters.  The first
         token of every request is sampled from the prefill logits and
         accounted to prefill/TTFT; only subsequent tokens count as decode
@@ -377,7 +380,7 @@ class ServeEngine:
         streams: Dict[int, List[int]] = {}
         rows: Dict[int, Dict[str, Any]] = {}
         ttfts: List[float] = []
-        tpot: List[float] = []
+        tick_s: List[float] = []
         ticks = 0
         busy_slot_ticks = 0
         prefill_s = 0.0
@@ -455,52 +458,57 @@ class ServeEngine:
         def do_tick() -> None:
             nonlocal cache, slots, ticks, busy_slot_ticks, decode_s
             ta = time.perf_counter()
-            if self.fault_injector is not None:
-                stall = self.fault_injector.fire("serve_stall")
-                if stall is not None and stall.seconds > 0:
-                    time.sleep(stall.seconds)  # a hung collective, simulated
             if self.paged:
-                cache, slots, sampled, finished = self._tick(
-                    self.params, cache, slots, self._pages_dev())
+                with span("serve/page_table"):
+                    extra = (self._pages_dev(),)
             else:
+                extra = ()
+            with span("serve/tick"):
+                if self.fault_injector is not None:
+                    stall = self.fault_injector.fire("serve_stall")
+                    if stall is not None and stall.seconds > 0:
+                        # a hung collective, simulated
+                        time.sleep(stall.seconds)
                 cache, slots, sampled, finished = self._tick(
-                    self.params, cache, slots)
-            sampled, finished = jax.device_get((sampled, finished))
+                    self.params, cache, slots, *extra)
+                sampled, finished = jax.device_get((sampled, finished))
             dt = time.perf_counter() - ta
             if self.watchdog_s > 0 and dt > self.watchdog_s:
                 raise EngineError(
                     f"no-progress watchdog: tick {ticks + 1} took {dt:.3f}s "
                     f"(> watchdog_s={self.watchdog_s}) with "
                     f"{len(slot_req)} request(s) in flight")
-            decode_s += dt
-            ticks += 1
-            busy_slot_ticks += len(slot_req)
-            for slot in list(slot_req):
-                r = slot_req[slot]
-                streams[r.rid].append(int(sampled[slot]))
-                tpot.append(dt)
-                if bool(finished[slot]):
-                    retire(slot, r)
-            if len(timeline) < 100_000:
-                sample = {"t_s": round(time.perf_counter() - t0, 6),
-                          "queue": len(pending), "busy": len(slot_req)}
-                if self.paged:
-                    sample["free_blocks"] = int(self._alloc.n_free)
-                timeline.append(sample)
+            with span("serve/schedule"):
+                decode_s += dt
+                tick_s.append(dt)
+                ticks += 1
+                busy_slot_ticks += len(slot_req)
+                for slot in list(slot_req):
+                    r = slot_req[slot]
+                    streams[r.rid].append(int(sampled[slot]))
+                    if bool(finished[slot]):
+                        retire(slot, r)
+                if len(timeline) < 100_000:
+                    sample = {"t_s": round(time.perf_counter() - t0, 6),
+                              "queue": len(pending), "busy": len(slot_req)}
+                    if self.paged:
+                        sample["free_blocks"] = int(self._alloc.n_free)
+                    timeline.append(sample)
 
         def admit_dense(r: Request) -> None:
             nonlocal cache, slots, prefill_s
             slot = free.pop()
             admit = self._admit_fn(r.prompt_len)
             ta = time.perf_counter()
-            cache, slots, tok, fin = admit(
-                self.params, cache, slots,
-                jnp.asarray(r.prompt, jnp.int32),
-                jnp.int32(slot), request_key(r.seed),
-                jnp.float32(r.temperature), jnp.int32(r.top_k),
-                jnp.float32(r.top_p), jnp.int32(budgets[r.rid]),
-                jnp.int32(r.eos_id))
-            tok, fin = jax.device_get((tok, fin))
+            with span("serve/prefill_chunk"):   # the whole prompt, one chunk
+                cache, slots, tok, fin = admit(
+                    self.params, cache, slots,
+                    jnp.asarray(r.prompt, jnp.int32),
+                    jnp.int32(slot), request_key(r.seed),
+                    jnp.float32(r.temperature), jnp.int32(r.top_k),
+                    jnp.float32(r.top_p), jnp.int32(budgets[r.rid]),
+                    jnp.int32(r.eos_id))
+                tok, fin = jax.device_get((tok, fin))
             tb = time.perf_counter()
             prefill_s += tb - ta
             finish_admission(r, slot, int(tok), bool(fin), tb - ta, tb,
@@ -548,7 +556,8 @@ class ServeEngine:
             self._pt[slot, :len(blocks)] = blocks
             self._pt_dev = None
             self._req_blocks[r.rid] = blocks
-            row_dev = jnp.asarray(self._pt[slot])
+            with span("serve/page_table"):
+                row_dev = jnp.asarray(self._pt[slot])
             S = len(matched) * bl
             cached_prompt_tokens += S
             total_prompt_tokens += P
@@ -559,24 +568,27 @@ class ServeEngine:
                 seg = prompt[lo:min(lo + C, P)]
                 toks = np.zeros((C,), np.int32)
                 toks[:len(seg)] = seg
-                logits, cache = self._chunk(
-                    self.params, cache, row_dev, jnp.asarray(toks),
-                    jnp.int32(lo), jnp.int32(len(seg)))
+                with span("serve/prefill_chunk"):
+                    logits, cache = self._chunk(
+                        self.params, cache, row_dev, jnp.asarray(toks),
+                        jnp.int32(lo), jnp.int32(len(seg)))
                 if ci < n_chunks - 1 and slot_req:
                     prefill_s += time.perf_counter() - ta
                     do_tick()       # co-residents advance between chunks
                     interleaved_ticks += 1
                     ta = time.perf_counter()
-            tok = int(jax.device_get(self._first(
-                logits, request_key(r.seed), jnp.float32(r.temperature),
-                jnp.int32(r.top_k), jnp.float32(r.top_p))))
+            with span("serve/first_token"):
+                tok = int(jax.device_get(self._first(
+                    logits, request_key(r.seed), jnp.float32(r.temperature),
+                    jnp.int32(r.top_k), jnp.float32(r.top_p))))
             fin = (r.eos_id >= 0 and tok == r.eos_id) or budget <= 1
-            slots = self._set_slot(
-                slots, jnp.int32(slot), jnp.int32(tok), jnp.int32(P),
-                jnp.asarray(not fin), jnp.int32(budget),
-                jnp.int32(r.eos_id), request_key(r.seed),
-                jnp.float32(r.temperature), jnp.int32(r.top_k),
-                jnp.float32(r.top_p))
+            with span("serve/slot_write"):
+                slots = self._set_slot(
+                    slots, jnp.int32(slot), jnp.int32(tok), jnp.int32(P),
+                    jnp.asarray(not fin), jnp.int32(budget),
+                    jnp.int32(r.eos_id), request_key(r.seed),
+                    jnp.float32(r.temperature), jnp.int32(r.top_k),
+                    jnp.float32(r.top_p))
             tb = time.perf_counter()
             prefill_s += tb - ta
             if self.prefix_cache:
@@ -614,52 +626,56 @@ class ServeEngine:
                 retire(slot, r)
 
         while pending or slot_req:
-            now = time.perf_counter() - t0
-            if deadlines_on and pending:
-                # queued requests past their deadline retire unserved —
-                # admitting them would spend prefill on a dead answer
-                keep: deque = deque()
-                for r in pending:
-                    exp = req_expiry(r)
-                    if exp is not None and now > exp:
-                        rows[r.rid] = {
-                            "id": r.rid, "slot": -1,
-                            "prompt_len": r.prompt_len,
-                            "max_new": budgets[r.rid],
-                            "arrival_s": r.arrival_s if realtime else 0.0,
-                            "cached_tokens": 0, "prefill_chunks": 0,
-                            "n_gen": 0, "gen_ids": [],
-                            "finish": "timeout",
-                            "done_s": round(now, 6),
-                        }
-                        timeouts += 1
-                    else:
-                        keep.append(r)
-                pending = keep
-            while free and pending and (not realtime
-                                        or pending[0].arrival_s <= now):
-                r = pending[0]
-                if self.paged:
-                    if not admit_paged(r):
-                        break
-                else:
-                    admit_dense(r)
-                pending.popleft()
+            with span("serve/schedule"):
                 now = time.perf_counter() - t0
+                if deadlines_on and pending:
+                    # queued requests past their deadline retire unserved —
+                    # admitting them would spend prefill on a dead answer
+                    keep: deque = deque()
+                    for r in pending:
+                        exp = req_expiry(r)
+                        if exp is not None and now > exp:
+                            rows[r.rid] = {
+                                "id": r.rid, "slot": -1,
+                                "prompt_len": r.prompt_len,
+                                "max_new": budgets[r.rid],
+                                "arrival_s": r.arrival_s if realtime else 0.0,
+                                "cached_tokens": 0, "prefill_chunks": 0,
+                                "n_gen": 0, "gen_ids": [],
+                                "finish": "timeout",
+                                "done_s": round(now, 6),
+                            }
+                            timeouts += 1
+                        else:
+                            keep.append(r)
+                    pending = keep
+                while free and pending and (not realtime
+                                            or pending[0].arrival_s <= now):
+                    r = pending[0]
+                    if self.paged:
+                        if not admit_paged(r):
+                            break
+                    else:
+                        admit_dense(r)
+                    pending.popleft()
+                    now = time.perf_counter() - t0
             if not slot_req:
                 if pending and realtime:
-                    time.sleep(min(max(pending[0].arrival_s - now, 0.0), 0.05))
+                    with span("serve/idle"):
+                        time.sleep(min(max(pending[0].arrival_s - now, 0.0),
+                                       0.05))
                 continue
             do_tick()
             if deadlines_on and slot_req:
-                now = time.perf_counter() - t0
-                for slot in list(slot_req):
-                    r = slot_req[slot]
-                    exp = req_expiry(r)
-                    if exp is not None and now > exp \
-                            and "n_gen" not in rows[r.rid]:
-                        retire(slot, r, finish="timeout")
-                        timeouts += 1
+                with span("serve/schedule"):
+                    now = time.perf_counter() - t0
+                    for slot in list(slot_req):
+                        r = slot_req[slot]
+                        exp = req_expiry(r)
+                        if exp is not None and now > exp \
+                                and "n_gen" not in rows[r.rid]:
+                            retire(slot, r, finish="timeout")
+                            timeouts += 1
 
         elapsed = time.perf_counter() - t0
         gen_tokens = sum(len(s) for s in streams.values())
@@ -695,7 +711,9 @@ class ServeEngine:
             # in prefill compute — from the per-request lifecycle anchors
             "queue_s": percentiles([w["queue_s"] for w in admitted
                                     if "queue_s" in w]),
-            "tpot_ms": percentiles([t * 1000 for t in tpot]),
+            # each decode tick's wall time, dispatch to tokens on the host,
+            # once per tick (a token's latency is one tick per slot)
+            "tick_ms": percentiles([t * 1000 for t in tick_s]),
             # hit/cold split: prefix-cache hits should beat cold prefills on
             # both the queue-free admission time and end-to-end TTFT
             "prefill_cache_hit_rate": (
@@ -729,7 +747,7 @@ class ServeEngine:
                 "completed": result["completed"],
                 "ticks": ticks,
             }
-            for key in ("ttft_s", "queue_s", "tpot_ms"):
+            for key in ("ttft_s", "queue_s", "tick_ms"):
                 p = result.get(key) or {}
                 if isinstance(p, dict) and "p50" in p:
                     headline[f"{key}_p50"] = p["p50"]

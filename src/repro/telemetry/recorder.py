@@ -6,11 +6,12 @@ hands it to the configured sink.  Three emission surfaces:
 
 - ``metric(step, values)`` — a windowed scalar observation.
 - ``event(name, step=..., **attrs)`` — a point occurrence.
-- ``span(name)`` (context manager) / ``span_row(name, t0, t1)`` —
-  timed intervals.  Span ids come from a per-recorder counter assigned
-  in *open* order and ``parent_id``/``depth`` from the recorder's open
-  stack, so two identical executions produce the identical span tree
-  (names, ids, parents, depths, seq order) even though wall times vary.
+- ``span(name)`` (context manager, also a profiler annotation) /
+  ``span_row(name, t0, t1)`` — timed intervals.  Span ids come from a
+  per-recorder counter assigned in *open* order and ``parent_id``/
+  ``depth`` from the recorder's open stack, so two identical executions
+  produce the identical span tree (names, ids, parents, depths, seq
+  order) even though wall times vary.
 
 Emission never touches the computation being measured: the recorder
 reads already-computed values and timestamps only, which is what makes
@@ -19,7 +20,6 @@ telemetry-on vs. telemetry-off runs bitwise identical.
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
 
 from .events import SCHEMA_VERSION
@@ -44,8 +44,8 @@ class TelemetryRecorder:
         self.counts: Dict[str, int] = {"metric": 0, "span": 0, "event": 0}
         self._seq = 0
         self._next_span_id = 0
-        # (span_id, name, t_open) for spans opened via the context manager
-        self._stack: List[Tuple[int, str, float]] = []
+        # ids of the spans open via the context manager, innermost last
+        self._stack: List[int] = []
         self._depths: Dict[int, int] = {}  # span_id -> ancestor count
         self._closed = False
 
@@ -108,7 +108,7 @@ class TelemetryRecorder:
         sid = self._next_span_id
         self._next_span_id += 1
         if parent is None and self._stack:
-            parent = self._stack[-1][0]
+            parent = self._stack[-1]
         depth = 0 if parent is None else self._depths.get(parent, 0) + 1
         self._depths[sid] = depth
         payload: Dict[str, Any] = {
@@ -126,35 +126,44 @@ class TelemetryRecorder:
         self._emit("span", payload, step, t_s=t1 - self.t0)
         return sid
 
-    @contextmanager
     def span(self, name: str, step: Optional[int] = None, **attrs: Any):
-        """Open a nested span; the row is emitted when the block exits
-        (children close first; ids still reflect open order)."""
+        """Open a nested span (a context manager yielding its id); the row
+        is emitted when the block exits (children close first; ids still
+        reflect open order). The span is also a profiler annotation
+        (:func:`repro.telemetry.spans.span`), and writes no row when the
+        recorder's spans are off."""
+        from .spans import span
+
+        return span(name, self, step, **attrs)
+
+    def open_span(self) -> Tuple[int, Optional[int], int]:
+        """Push a span: its id, its parent's id and its depth."""
         sid = self._next_span_id
         self._next_span_id += 1
-        parent = self._stack[-1][0] if self._stack else None
+        parent = self._stack[-1] if self._stack else None
         depth = 0 if parent is None else self._depths.get(parent, 0) + 1
         self._depths[sid] = depth
-        t_open = time.perf_counter()
-        self._stack.append((sid, name, t_open))
-        try:
-            yield sid
-        finally:
-            self._stack.pop()
-            t_close = time.perf_counter()
-            payload: Dict[str, Any] = {
-                "name": name,
-                "span_id": sid,
-                "parent_id": parent,
-                "depth": depth,
-                "t0_s": t_open - self.t0,
-                "t1_s": t_close - self.t0,
-                "dur_s": t_close - t_open,
-            }
-            a = _clean_attrs(attrs)
-            if a:
-                payload["attrs"] = a
-            self._emit("span", payload, step, t_s=t_close - self.t0)
+        self._stack.append(sid)
+        return sid, parent, depth
+
+    def close_span(self, name: str, sid: int, parent: Optional[int],
+                   depth: int, t_open: float, t_close: float,
+                   step: Optional[int], attrs: Dict[str, Any]) -> None:
+        """Pop the span ``open_span`` pushed and emit its row."""
+        self._stack.pop()
+        payload: Dict[str, Any] = {
+            "name": name,
+            "span_id": sid,
+            "parent_id": parent,
+            "depth": depth,
+            "t0_s": t_open - self.t0,
+            "t1_s": t_close - self.t0,
+            "dur_s": t_close - t_open,
+        }
+        a = _clean_attrs(attrs)
+        if a:
+            payload["attrs"] = a
+        self._emit("span", payload, step, t_s=t_close - self.t0)
 
     # -- lifecycle ----------------------------------------------------------
     @property

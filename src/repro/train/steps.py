@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from ..models import base as B
 from ..models.common import sharded_cross_entropy
+from ..telemetry.spans import LOSS, OPTIMIZER, SAMPLE, scope
 
 
 def compute_loss(model, params, batch, mesh_ctx=None, storage_axes=(),
@@ -19,7 +20,8 @@ def compute_loss(model, params, batch, mesh_ctx=None, storage_axes=(),
     if cfg.n_patches:
         logits = logits[:, cfg.n_patches:]
     mask = batch.get("loss_mask")
-    loss = sharded_cross_entropy(logits, batch["labels"], mask)
+    with scope(LOSS):
+        loss = sharded_cross_entropy(logits, batch["labels"], mask)
     total = loss
     if "router_lb" in aux:
         total = total + aux["router_lb"]
@@ -80,7 +82,9 @@ def make_train_step(model, optimizer, mesh_ctx: Optional[B.MeshContext] = None,
             (_, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 state["params"], batch
             )
-        new_params, new_opt = optimizer.update(grads, state["opt"], state["params"])
+        with scope(OPTIMIZER):
+            new_params, new_opt = optimizer.update(grads, state["opt"],
+                                                   state["params"])
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         metrics = dict(metrics)
@@ -176,6 +180,7 @@ def make_engine_step(model, mesh_ctx: Optional[B.MeshContext] = None,
     """
     from ..serve.sampling import sample_tokens
 
+    @scope(SAMPLE)
     def _sample_and_advance(slots, logits, new_cache):
         if greedy:
             sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -413,13 +413,15 @@ def test_compile_cache_dir_from_env_or_checkout(monkeypatch):
     calls = []
     monkeypatch.setattr(jax.config, "update",
                         lambda key, value: calls.append((key, value)))
+    key = ("jax_compilation_cache_include_metadata_in_key", True)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax")
     assert cli.use_compile_cache() == "/elsewhere/jax"
-    assert calls == []           # JAX reads its own variable; no code path
+    assert calls == [key]        # JAX reads its own variable; no code path
+    calls.clear()
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     path = cli.use_compile_cache()
     assert path == os.path.join(os.path.abspath(ROOT), ".jax_cache")
-    assert calls == [("jax_compilation_cache_dir", path)]
+    assert calls == [key, ("jax_compilation_cache_dir", path)]
 
 
 # ---------------------------------------------------------------------------

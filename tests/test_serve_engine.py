@@ -116,7 +116,7 @@ def test_engine_metrics_shape():
     assert res["generated_tokens"] == 16
     assert res["decode_tokens"] == 12          # firsts belong to prefill
     assert set(res["ttft_s"]) == {"p50", "p95", "p99"}
-    assert set(res["tpot_ms"]) == {"p50", "p95", "p99"}
+    assert set(res["tick_ms"]) == {"p50", "p95", "p99"}
     assert 0 < res["slot_utilization"] <= 1
     for row in res["requests"]:
         assert row["n_gen"] == len(row["gen_ids"]) == 4

@@ -1,6 +1,8 @@
 """Unified telemetry: row schema, sinks, span determinism, MFU/goodput
-accounting, profiler hook, and the no-observer-effect contract (telemetry
-on vs. off loss curves are bitwise identical)."""
+accounting, profiler hook, the gym's and engine's phase spans on the
+profiler clock, and the no-observer-effect contract (telemetry on vs. off
+loss curves are bitwise identical)."""
+import glob
 import os
 
 import jax
@@ -26,6 +28,7 @@ from repro.telemetry.sinks import (
     read_csv,
     read_jsonl,
 )
+from repro.telemetry.spans import span
 
 
 def _recorder(**kw):
@@ -427,6 +430,38 @@ def test_profiler_window_that_cannot_start_fails_the_run(tmp_path,
                                              "num_steps": 1}}}))
 
 
+def _host_events(trace_dir):
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert files, trace_dir
+    pd = jax.profiler.ProfileData.from_file(files[0])
+    return [e.name for p in pd.planes if p.name.startswith("/host:")
+            for ln in p.lines for e in ln.events]
+
+
+def test_gym_spans_in_profiler_trace_and_rows_unchanged(tmp_path):
+    """The operator's path: a ``telemetry.profile`` window holds the gym's
+    phases as host events, and the telemetry rows keep their tree."""
+    result = run_api.execute_doc(_train_doc(
+        tmp_path, "prof", steps=4,
+        train={"telemetry": {"profile": {"start_step": 2, "num_steps": 2}}}))
+    names = _host_events(result["profile_trace"])
+    assert names.count("gym/step") == 2          # steps 2 and 3
+    assert "gym/data_wait" in names and "gym/flush" in names
+    rows = read_jsonl(result["telemetry"]["file"], validate=True)
+    spans = [r for r in rows if r["type"] == "span"]
+    # per step: data_wait then step, ids in open order, no parents; the
+    # flush of the previous step's metrics follows from step 2 on
+    shape = [(r["name"], r.get("step")) for r in spans]
+    assert shape[:5] == [("gym/data_wait", 1), ("gym/step", 1),
+                         ("gym/data_wait", 2), ("gym/step", 2),
+                         ("gym/flush", 1)]
+    assert [r["span_id"] for r in spans] == sorted(r["span_id"]
+                                                   for r in spans)
+    assert all(r["parent_id"] is None and r["depth"] == 0 for r in spans)
+    assert sum(r["name"] == "gym/step" for r in spans) == 4
+
+
 def test_csv_sink_through_run(tmp_path):
     result = run_api.execute_doc(_train_doc(
         tmp_path, "csvr", steps=2, train={"telemetry": {"sink": "csv"}}))
@@ -479,6 +514,49 @@ def test_serve_request_lifecycle_spans():
     assert all(set(t) >= {"t_s", "queue", "busy"} for t in tl)
     headline = [r for r in rec.rows if r["type"] == "metric"]
     assert headline and "tok_s" in headline[-1]["data"]
+
+
+def test_engine_phase_spans_in_profiler_trace(tmp_path):
+    from repro.configs import get_reduced
+    from repro.models import build_model
+    from repro.serve import Request, ServeEngine
+
+    model = build_model(get_reduced("qwen1p5_0p5b"))
+    params = model.init(jax.random.PRNGKey(0))
+    rec = _recorder(kind="serve")
+    engine = ServeEngine(model, params, n_slots=2, max_len=32, block_len=4,
+                         prefill_chunk=8, greedy=True, telemetry=rec)
+    reqs = [Request(rid=i, prompt=tuple(range(1, 12)), max_new=4, seed=i)
+            for i in range(3)]
+    engine._warmup([r.prompt_len for r in reqs])
+    with jax.profiler.trace(str(tmp_path)):
+        res = engine.run(reqs, realtime=False, warmup=False)
+    names = _host_events(str(tmp_path))
+    assert names.count("serve/tick") == res["ticks"]
+    for phase in ("serve/schedule", "serve/prefill_chunk",
+                  "serve/first_token", "serve/slot_write",
+                  "serve/page_table"):
+        assert phase in names, phase
+    # the phases are trace-only: the rows are the per-request lifecycle
+    assert validate_rows(rec.rows) == len(rec.rows)
+    assert {r["name"] for r in rec.rows if r["type"] == "span"} == {
+        "serve/request", "serve/queued", "serve/prefill", "serve/decode"}
+    assert len(res["tick_ms"]) == 3 and res["tick_ms"]["p50"] > 0
+
+
+def test_span_rows_only_with_spans_on(tmp_path):
+    on = _recorder()
+    off = _recorder(spans=False)
+    with jax.profiler.trace(str(tmp_path)):
+        with span("gym/step", on, step=3) as sid:
+            assert sid == 0
+        with off.span("gym/step"):
+            pass
+        with span("serve/tick"):
+            pass
+    assert [(r["name"], r["step"]) for r in on.rows] == [("gym/step", 3)]
+    assert off.rows == []
+    assert _host_events(str(tmp_path)).count("gym/step") == 2
 
 
 # ---------------------------------------------------------------------------
