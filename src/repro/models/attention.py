@@ -371,12 +371,16 @@ def mla_init_cache(cfg: B.ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat
 
 
 # ---------------------------------------------------------------------------
-# Paged KV cache (serving): [n_blocks, block_len, ...] leaves + page tables
+# Paged KV cache (serving): [L, n_blocks, block_len, ...] leaves + page tables
 # ---------------------------------------------------------------------------
 # The serve engine's block allocator hands each request a row of physical
 # block ids; attention reads the cache *through* that row (gather) and
 # writes the current token's K/V into (block, offset) = (row[pos // bl],
-# pos % bl) (scatter).  Two JAX indexing facts are load-bearing here:
+# pos % bl) (scatter).  Each primitive takes the whole full-depth leaf and
+# its layer index: the layer loop carries the pool, so a layer's write is
+# one in-place scatter into the carried (donated) buffer and its read one
+# gather of just the pages in the rows — no per-layer slice is materialised.
+# Two JAX indexing facts are load-bearing here, on axis 1 (the block axis):
 #
 # - gathers CLAMP out-of-bounds indices and WRAP negative ones, so a page
 #   table's -1 (unallocated) entries resolve to real-but-wrong pages whose
@@ -387,88 +391,105 @@ def mla_init_cache(cfg: B.ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat
 #   ``n_blocks``.  -1 would WRAP and corrupt the last live block.
 
 
-def paged_view(leaf, pages):
-    """Gather ``leaf [n_blocks, bl, ...]`` through ``pages [..., n_pages]``
-    into a contiguous view ``[..., n_pages * bl, ...]``."""
+def paged_view(leaf, layer, pages):
+    """Gather layer ``layer`` of ``leaf [L, n_blocks, bl, ...]`` through
+    ``pages [..., n_pages]`` into a contiguous view ``[..., n_pages * bl,
+    ...]``."""
     with scope(KV_READ):
-        v = leaf[pages]
+        v = leaf[layer, pages]
         lead = pages.shape[:-1]
-        return v.reshape(lead + (pages.shape[-1] * leaf.shape[1],)
-                         + leaf.shape[2:])
+        return v.reshape(lead + (pages.shape[-1] * leaf.shape[2],)
+                         + leaf.shape[3:])
 
 
-def _paged_write(leaf, phys, off, vals):
-    """Scatter ``vals [N, ...]`` rows into ``leaf[phys[i], off[i]]``
-    (``phys == n_blocks`` drops the write)."""
+def _paged_write(leaf, layer, phys, off, vals):
+    """Scatter ``vals [N, ...]`` rows, flattened to the leaf's feature
+    shape, into ``leaf[layer, phys[i], off[i]]`` (``phys == n_blocks``
+    drops the write)."""
     with scope(KV_WRITE):
-        return leaf.at[phys, off].set(vals.astype(leaf.dtype))
+        vals = vals.reshape(vals.shape[:1] + leaf.shape[3:])
+        return leaf.at[layer, phys, off].set(vals.astype(leaf.dtype))
 
 
 def gqa_init_paged_cache(cfg: B.ArchConfig, n_blocks: int, block_len: int,
                          dtype=jnp.bfloat16):
+    """K/V pages of ``block_len`` rows of ``K * dh`` features, heads-major.
+
+    One feature axis, not ``[K, dh]``: with a head size under 128 lanes the
+    TPU's default layout of a ``[.., bl, K, dh]`` leaf puts the block axis
+    minor-most, and the compiler then copies the whole pool into and out of
+    a row-major layout around the layer loop for the page gathers."""
     K, dh = cfg.n_kv_heads, cfg.head_dim_
     return {
-        "k": jnp.zeros((n_blocks, block_len, K, dh), dtype),
-        "v": jnp.zeros((n_blocks, block_len, K, dh), dtype),
+        "k": jnp.zeros((n_blocks, block_len, K * dh), dtype),
+        "v": jnp.zeros((n_blocks, block_len, K * dh), dtype),
     }
 
 
-def gqa_decode_paged(cfg: B.ArchConfig, p, cache, x, positions, pages,
+def _gqa_paged_kv(ck, cv, layer, pages, heads):
+    """K and V views through ``pages [B, n_pages]``, features split back
+    into ``heads`` = (K, dh): ``[B, n_pages * bl, K, dh]``."""
+    shape = pages.shape[:1] + (-1,) + tuple(heads)
+    return (paged_view(ck, layer, pages).reshape(shape),
+            paged_view(cv, layer, pages).reshape(shape))
+
+
+def gqa_decode_paged(cfg: B.ArchConfig, p, cache, layer, x, positions, pages,
                      active=None):
     """Single-token GQA decode through page tables.
 
+    ``cache`` holds the full-depth pool, ``layer`` this layer's index in it;
     x [B,1,D]; positions [B]; pages int32 [B, n_pages] physical block ids
     per slot; active bool [B] suppresses cache writes for dead slots (their
     frozen positions may alias pages since freed and reused)."""
     q, k, v = _project_qkv(p, x, cfg)
     q = apply_rope(q, positions[:, None], cfg.rope_theta)
     k = apply_rope(k, positions[:, None], cfg.rope_theta)
-    nb, bl = cache["k"].shape[:2]
+    nb, bl = cache["k"].shape[1:3]
     phys = jnp.take_along_axis(pages, (positions // bl)[:, None], axis=1)[:, 0]
     if active is not None:
         phys = jnp.where(active, phys, nb)
-    ck = _paged_write(cache["k"], phys, positions % bl, k[:, 0])
-    cv = _paged_write(cache["v"], phys, positions % bl, v[:, 0])
-    vk = paged_view(ck, pages)                                   # [B,T,K,dh]
-    vv = paged_view(cv, pages)
+    ck = _paged_write(cache["k"], layer, phys, positions % bl, k[:, 0])
+    cv = _paged_write(cache["v"], layer, phys, positions % bl, v[:, 0])
+    vk, vv = _gqa_paged_kv(ck, cv, layer, pages, k.shape[2:])    # [B,T,K,dh]
     dh = q.shape[-1]
     scores = _gqa_scores_einsum(q, vk).astype(jnp.float32) / math.sqrt(dh)
     T = vk.shape[1]
     valid = jnp.arange(T)[None, :] < (positions + 1)[:, None]    # [B,T]
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    o = _gqa_out_einsum(probs, vv)
+    o = _gqa_out_einsum(probs, vv.astype(x.dtype))
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype))
     return out, {"k": ck, "v": cv}
 
 
-def gqa_prefill_chunk(cfg: B.ArchConfig, p, cache, x, positions, pages_row,
-                      n_valid):
+def gqa_prefill_chunk(cfg: B.ArchConfig, p, cache, layer, x, positions,
+                      pages_row, n_valid):
     """One fixed-shape prefill chunk: C prompt rows into one request's pages.
 
-    x [1,C,D]; positions [C] absolute; pages_row int32 [n_pages]; rows at
-    index >= n_valid are padding (writes dropped, outputs garbage).  The
-    chunk shape never depends on the prompt length, so a page's stored K/V
-    is bitwise identical whether the prompt was short or long, cold or a
-    cache hit — the canonical-page property the radix index shares under.
+    ``cache``/``layer`` as in ``gqa_decode_paged``; x [1,C,D]; positions
+    [C] absolute; pages_row int32 [n_pages]; rows at index >= n_valid are
+    padding (writes dropped, outputs garbage).  The chunk shape never
+    depends on the prompt length, so a page's stored K/V is bitwise
+    identical whether the prompt was short or long, cold or a cache hit —
+    the canonical-page property the radix index shares under.
     """
     q, k, v = _project_qkv(p, x, cfg)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    nb, bl = cache["k"].shape[:2]
+    nb, bl = cache["k"].shape[1:3]
     row_idx = jnp.arange(positions.shape[0])
     phys = jnp.where(row_idx < n_valid, pages_row[positions // bl], nb)
-    ck = _paged_write(cache["k"], phys, positions % bl, k[0])
-    cv = _paged_write(cache["v"], phys, positions % bl, v[0])
-    vk = paged_view(ck, pages_row[None])                        # [1,T,K,dh]
-    vv = paged_view(cv, pages_row[None])
+    ck = _paged_write(cache["k"], layer, phys, positions % bl, k[0])
+    cv = _paged_write(cache["v"], layer, phys, positions % bl, v[0])
+    vk, vv = _gqa_paged_kv(ck, cv, layer, pages_row[None], k.shape[2:])
     dh = q.shape[-1]
     scores = _gqa_scores_einsum(q, vk).astype(jnp.float32) / math.sqrt(dh)
     T = vk.shape[1]
     valid = positions[:, None] >= jnp.arange(T)[None, :]         # [C,T] causal
     scores = jnp.where(valid[None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    o = _gqa_out_einsum(probs, vv)
+    o = _gqa_out_einsum(probs, vv.astype(x.dtype))
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype))
     return out, {"k": ck, "v": cv}
 
@@ -482,19 +503,22 @@ def mla_init_paged_cache(cfg: B.ArchConfig, n_blocks: int, block_len: int,
     }
 
 
-def mla_decode_paged(cfg: B.ArchConfig, p, cache, x, positions, pages,
+def mla_decode_paged(cfg: B.ArchConfig, p, cache, layer, x, positions, pages,
                      active=None, absorb: bool = False):
-    """Single-token MLA decode against the paged latent cache."""
+    """Single-token MLA decode against the paged latent cache (see
+    ``gqa_decode_paged``)."""
     m = cfg.mla
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(cfg, p, x, positions[:, None])
-    nb, bl = cache["c_kv"].shape[:2]
+    nb, bl = cache["c_kv"].shape[1:3]
     phys = jnp.take_along_axis(pages, (positions // bl)[:, None], axis=1)[:, 0]
     if active is not None:
         phys = jnp.where(active, phys, nb)
-    cc = _paged_write(cache["c_kv"], phys, positions % bl, c_kv[:, 0])
-    cr = _paged_write(cache["k_rope"], phys, positions % bl, k_rope[:, 0])
-    vc = paged_view(cc, pages)                                   # [B,T,r]
-    vr = paged_view(cr, pages)
+    cc = _paged_write(cache["c_kv"], layer, phys, positions % bl,
+                      c_kv[:, 0])
+    cr = _paged_write(cache["k_rope"], layer, phys, positions % bl,
+                      k_rope[:, 0])
+    vc = paged_view(cc, layer, pages)  # [B,T,r]
+    vr = paged_view(cr, layer, pages)
     scale = 1.0 / math.sqrt(m.head_dim_nope + m.head_dim_rope)
     T = vc.shape[1]
     valid = jnp.arange(T)[None, :] <= positions[:, None]
@@ -522,18 +546,18 @@ def mla_decode_paged(cfg: B.ArchConfig, p, cache, x, positions, pages,
     return out, {"c_kv": cc, "k_rope": cr}
 
 
-def mla_prefill_chunk(cfg: B.ArchConfig, p, cache, x, positions, pages_row,
-                      n_valid):
+def mla_prefill_chunk(cfg: B.ArchConfig, p, cache, layer, x, positions,
+                      pages_row, n_valid):
     """One fixed-shape MLA prefill chunk (see ``gqa_prefill_chunk``)."""
     m = cfg.mla
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(cfg, p, x, positions)
-    nb, bl = cache["c_kv"].shape[:2]
+    nb, bl = cache["c_kv"].shape[1:3]
     row_idx = jnp.arange(positions.shape[0])
     phys = jnp.where(row_idx < n_valid, pages_row[positions // bl], nb)
-    cc = _paged_write(cache["c_kv"], phys, positions % bl, c_kv[0])
-    cr = _paged_write(cache["k_rope"], phys, positions % bl, k_rope[0])
-    vc = paged_view(cc, pages_row[None])                         # [1,T,r]
-    vr = paged_view(cr, pages_row[None])
+    cc = _paged_write(cache["c_kv"], layer, phys, positions % bl, c_kv[0])
+    cr = _paged_write(cache["k_rope"], layer, phys, positions % bl, k_rope[0])
+    vc = paged_view(cc, layer, pages_row[None])  # [1,T,r]
+    vr = paged_view(cr, layer, pages_row[None])
     scale = 1.0 / math.sqrt(m.head_dim_nope + m.head_dim_rope)
     T = vc.shape[1]
     k_nope, v = _mla_expand_kv(cfg, p, vc.astype(x.dtype))

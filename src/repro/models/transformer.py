@@ -140,35 +140,39 @@ def decode_block(cfg, kind, p, cache, x, positions, mesh_ctx=None,
     return x, new_cache, jnp.zeros((), jnp.float32)
 
 
-def decode_block_paged(cfg, kind, p, cache, x, positions, pages, active,
-                       mesh_ctx=None, storage_axes=()):
-    """``decode_block`` reading/writing K/V through page tables."""
+def decode_block_paged(cfg, kind, p, cache, layer, x, positions, pages,
+                       active, mesh_ctx=None, storage_axes=()):
+    """``decode_block`` reading/writing K/V through page tables, at layer
+    ``layer`` of the full-depth pool ``cache``."""
     with scope(ATTENTION):
         h = apply_norm(cfg, p["attn_norm"], x)
         if cfg.mla:
-            h, new_cache = A.mla_decode_paged(cfg, p["attn"], cache, h,
-                                              positions, pages, active,
+            h, new_cache = A.mla_decode_paged(cfg, p["attn"], cache, layer,
+                                              h, positions, pages, active,
                                               absorb=cfg.mla_absorb)
         else:
-            h, new_cache = A.gqa_decode_paged(cfg, p["attn"], cache, h,
-                                              positions, pages, active)
+            h, new_cache = A.gqa_decode_paged(cfg, p["attn"], cache, layer,
+                                              h, positions, pages, active)
         x = x + h
     x, _ = _mlp_residual(cfg, kind, p, x, mesh_ctx, storage_axes)
     return x, new_cache
 
 
-def prefill_chunk_block(cfg, kind, p, cache, x, positions, pages_row, n_valid,
-                        mesh_ctx=None, storage_axes=()):
-    """One layer of the fixed-shape chunked-prefill program."""
+def prefill_chunk_block(cfg, kind, p, cache, layer, x, positions, pages_row,
+                        n_valid, mesh_ctx=None, storage_axes=()):
+    """One layer of the fixed-shape chunked-prefill program, at layer
+    ``layer`` of the full-depth pool ``cache``."""
     x = B.constrain(x, mesh_ctx)
     with scope(ATTENTION):
         h = apply_norm(cfg, p["attn_norm"], x)
         if cfg.mla:
-            h, new_cache = A.mla_prefill_chunk(cfg, p["attn"], cache, h,
-                                               positions, pages_row, n_valid)
+            h, new_cache = A.mla_prefill_chunk(cfg, p["attn"], cache, layer,
+                                               h, positions, pages_row,
+                                               n_valid)
         else:
-            h, new_cache = A.gqa_prefill_chunk(cfg, p["attn"], cache, h,
-                                               positions, pages_row, n_valid)
+            h, new_cache = A.gqa_prefill_chunk(cfg, p["attn"], cache, layer,
+                                               h, positions, pages_row,
+                                               n_valid)
         x = x + h
     x, _ = _mlp_residual(cfg, kind, p, x, mesh_ctx, storage_axes)
     return B.constrain(x, mesh_ctx), new_cache
@@ -587,6 +591,25 @@ class DecoderLM(B.Model):
             )
         return cache
 
+    def _paged_layers(self, params, cache, x, block):
+        """The paged layer loop of ``prefill_chunk`` and the paged
+        ``decode_step``: per stack, a scan over (layer params, layer index)
+        that carries ``(x, pool)``, the pool whole at ``[L, n_blocks, bl,
+        ...]``.  ``block(kind, lp, pool, i, x) -> (x, pool)`` writes and
+        reads layer ``i`` of the pool.  Carried, not passed as scan inputs
+        and outputs, the donated pool is updated in place instead of being
+        sliced per layer and restacked into a new buffer."""
+        new_cache: Dict[str, Any] = {}
+        for name, kind, idxs in self._stacks():
+
+            def body(carry, inp, kind=kind):
+                lp, i = inp
+                return block(kind, lp, carry[1], i, carry[0]), None
+
+            (x, new_cache[name]), _ = jax.lax.scan(
+                body, (x, cache[name]), (params[name], jnp.arange(len(idxs))))
+        return x, new_cache
+
     def prefill_chunk(self, params, cache, pages_row, tokens, start, n_valid,
                       mesh_ctx=None, storage_axes=()):
         """Run one fixed-shape prompt chunk into a request's pages.
@@ -601,19 +624,13 @@ class DecoderLM(B.Model):
         x = self.embed_tokens(params, tokens[None])
         x = B.constrain(x, mesh_ctx)
         positions = start + jnp.arange(tokens.shape[0])
-        new_cache: Dict[str, Any] = {}
-        for name, kind, idxs in self._stacks():
 
-            def body(x, inp, kind=kind):
-                lp, lc = inp
-                x, nc = prefill_chunk_block(cfg, kind, lp, lc, x, positions,
-                                            pages_row, n_valid, mesh_ctx,
-                                            storage_axes)
-                return x, nc
+        def block(kind, lp, pool, i, x):
+            return prefill_chunk_block(cfg, kind, lp, pool, i, x, positions,
+                                       pages_row, n_valid, mesh_ctx,
+                                       storage_axes)
 
-            x, nc = ST.Stacked(body, len(idxs)).scan(
-                (params[name], cache[name]), x)
-            new_cache[name] = nc
+        x, new_cache = self._paged_layers(params, cache, x, block)
         last = jnp.take(x, n_valid - 1, axis=1)          # [1, D]
         logits = self.logits(params, last[:, None], mesh_ctx)[:, 0]
         return logits, new_cache
@@ -624,18 +641,12 @@ class DecoderLM(B.Model):
         x = self.embed_tokens(params, tokens[:, None])
         new_cache: Dict[str, Any] = {}
         if pages is not None:
-            for name, kind, idxs in self._stacks():
 
-                def pbody(x, inp, kind=kind):
-                    lp, lc = inp
-                    x, nc = decode_block_paged(cfg, kind, lp, lc, x,
-                                               positions, pages, active,
-                                               mesh_ctx)
-                    return x, nc
+            def block(kind, lp, pool, i, x):
+                return decode_block_paged(cfg, kind, lp, pool, i, x,
+                                          positions, pages, active, mesh_ctx)
 
-                x, nc = ST.Stacked(pbody, len(idxs)).scan(
-                    (params[name], cache[name]), x)
-                new_cache[name] = nc
+            x, new_cache = self._paged_layers(params, cache, x, block)
         elif cfg.arch_type == "hybrid":
             x, new_cache = self._decode_hybrid(params, cache, x, positions)
         else:

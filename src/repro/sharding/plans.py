@@ -308,9 +308,11 @@ def cache_shardings(plan: ShardingPlan, mesh: Mesh, cache_shapes,
             if len(shape) >= 3 and shape[2] % tp == 0:
                 s[2] = "model"
             return NamedSharding(mesh, P(*s))
-        # attention caches: [L, B, S, K, dh] or MLA [L, B, S, r]
+        # attention caches: [L, B, S, K, dh], paged GQA [L, n_blocks, bl,
+        # K * dh] (heads-major features) or MLA [L, B, S, r]
         seq_dim = 2 if len(shape) >= 3 else None
-        kv_dim = 3 if len(shape) >= 5 else None
+        paged_kv = len(shape) == 4 and name.endswith(("['k']", "['v']"))
+        kv_dim = 3 if len(shape) >= 5 or paged_kv else None
         if kv_dim is not None and shape[kv_dim] % tp == 0:
             s[kv_dim] = "model"
         elif seq_dim is not None and shape[seq_dim] % tp == 0:

@@ -8,7 +8,10 @@ correctness under slot churn and LRU eviction, chunked prefill never
 stalling a mid-decode slot past one chunk, and the paged knobs through
 the Run API.
 """
+import functools
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -203,6 +206,172 @@ def test_paged_engine_sharded_single_device_matches_unsharded():
     res = sharded.run(trace, realtime=False)
     assert [r["gen_ids"] for r in res["requests"]] == want
     assert res["prefill_cache_hit_rate"] > 0
+
+
+def test_paged_pool_shards_heads_like_the_dense_cache():
+    """A paged GQA leaf keeps its KV heads in one ``K * dh`` feature axis;
+    the cache plan puts the model axis there, as on the dense cache's
+    head axis, and the data axis on the block axis."""
+    from repro.launch.mesh import make_local_mesh
+    from repro.sharding.plans import cache_shardings, make_plan
+
+    model, _ = _model("qwen1p5_0p5b")
+    mesh = make_local_mesh(1, 1)
+    for plan in ("ddp", "fsdp_tp"):
+        paged = cache_shardings(make_plan(plan), mesh, jax.eval_shape(
+            lambda: model.init_paged_cache(8, 4)), 8)
+        dense = cache_shardings(make_plan(plan), mesh, jax.eval_shape(
+            lambda: model.init_cache(2, 8)), 2)
+        for leaf in ("k", "v"):
+            assert tuple(paged["blocks"][leaf].spec) == tuple(
+                dense["blocks"][leaf].spec)[:4]
+            assert tuple(paged["blocks"][leaf].spec)[3] == "model"
+
+
+# ---------------------------------------------------------------------------
+# the paged layer loop: the pool crosses it in place
+# ---------------------------------------------------------------------------
+# Four layers, so each stack's layer loop runs more than once (the MLA toy
+# then has one dense layer and a three-layer MoE stack).
+_LOOP_ARCHS = ["qwen1p5_0p5b", "deepseek_v3_671b"]
+
+
+def _engine_slots(n):
+    return {"tokens": jnp.arange(3, 3 + n, dtype=jnp.int32),
+            "pos": jnp.array([5, 9, 2][:n], jnp.int32),
+            "active": jnp.array([True, True, False][:n]),
+            "n_gen": jnp.zeros((n,), jnp.int32),
+            "max_gen": jnp.full((n,), 8, jnp.int32),
+            "eos": jnp.full((n,), -1, jnp.int32),
+            "key": jnp.zeros((n, 2), jnp.uint32),
+            "temperature": jnp.zeros((n,), jnp.float32),
+            "top_k": jnp.zeros((n,), jnp.int32),
+            "top_p": jnp.ones((n,), jnp.float32)}
+
+
+def _paged_programs(model, params, n_blocks, bl=8, n_slots=3, max_pages=4,
+                    chunk=8):
+    """The engine's two paged programs compiled with a float32 pool donated
+    (a bfloat16 scatter is widened on the CPU, which adds whole-pool
+    converts); returns (pool bytes, pool leaf shapes, compiled programs)."""
+    from repro.train import steps as ST
+
+    pool = jax.eval_shape(
+        lambda: model.init_paged_cache(n_blocks, bl, jnp.float32))
+    leaves = jax.tree_util.tree_leaves(pool)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    tick = jax.jit(ST.make_engine_step(model, paged=True),
+                   donate_argnums=(1, 2)).lower(
+        params, pool, _engine_slots(n_slots),
+        jax.ShapeDtypeStruct((n_slots, max_pages), jnp.int32)).compile()
+    prefill = jax.jit(ST.make_prefill_chunk_step(model),
+                      donate_argnums=(1,)).lower(
+        params, pool, jax.ShapeDtypeStruct((max_pages,), jnp.int32),
+        jax.ShapeDtypeStruct((chunk,), jnp.int32), i32, i32).compile()
+    return (sum(a.size * a.dtype.itemsize for a in leaves),
+            {tuple(a.shape) for a in leaves}, (tick, prefill))
+
+
+@pytest.mark.parametrize("arch", _LOOP_ARCHS)
+def test_paged_programs_update_the_pool_in_place(arch):
+    """The decode tick and the prefill chunk carry the donated pool through
+    the layer loop: their temporaries do not grow with the pool, and no
+    copy of a pool leaf appears in either compiled program."""
+    import re
+
+    model, params = _model(arch, n_layers=4)
+    small_b, _, small = _paged_programs(model, params, 64)
+    big_b, shapes, big = _paged_programs(model, params, 512)
+    copied = re.compile(r"= \w+\[([\d,]*)\]\S* copy\(")
+    for name, s, b in zip(("tick", "chunk"), small, big):
+        growth = (b.memory_analysis().temp_size_in_bytes
+                  - s.memory_analysis().temp_size_in_bytes)
+        assert growth < 0.1 * (big_b - small_b), (name, growth)
+        copies = {tuple(int(d) for d in m.group(1).split(","))
+                  for m in copied.finditer(b.as_text()) if m.group(1)}
+        assert not copies & shapes, (name, copies & shapes)
+
+
+def _per_layer_reference(model, params, pool, x, block):
+    """A plain Python ``for`` over the layers: each layer's block runs, as a
+    program of its own like one trip of the loop, on its own one-layer
+    slice of the pool, and the slices are restacked."""
+    new_pool = {}
+    for name, kind, idxs in model._stacks():
+        layer = jax.jit(functools.partial(block, kind))
+        slices = []
+        for i in range(len(idxs)):
+            lp = jax.tree_util.tree_map(lambda a: a[i], params[name])
+            lc = jax.tree_util.tree_map(lambda a: a[i:i + 1], pool[name])
+            x, lc = layer(lp, lc, x)
+            slices.append(lc)
+        new_pool[name] = jax.tree_util.tree_map(
+            lambda *a: jnp.concatenate(a), *slices)
+    return x, new_pool
+
+
+@pytest.mark.parametrize("arch", _LOOP_ARCHS)
+def test_paged_layer_loop_matches_per_layer_reference(arch):
+    """The carried layer loop of the decode tick and the prefill chunk
+    gives exactly the logits and pool of a per-layer loop over the same
+    blocks, at float32 on the CPU, with live, dropped and unallocated
+    (-1) page entries in play."""
+    from repro.models import transformer as T
+
+    model, params = _model(arch, n_layers=4)
+    cfg = model.cfg
+    n_blocks, bl = 12, 4
+    rng = np.random.default_rng(0)
+    pool = jax.tree_util.tree_map(
+        lambda a: jnp.asarray(rng.standard_normal(a.shape), jnp.float32),
+        model.init_paged_cache(n_blocks, bl, jnp.float32))
+    pages = jnp.asarray([[3, 7, 1, -1], [0, 10, 11, 2], [5, 6, -1, -1]],
+                        jnp.int32)
+    tokens = jnp.asarray([4, 9, 17], jnp.int32)
+    positions = jnp.asarray([5, 13, 2], jnp.int32)
+    active = jnp.asarray([True, True, False])
+
+    def ref_decode(params, pool):
+        def block(kind, lp, lc, x):
+            return T.decode_block_paged(cfg, kind, lp, lc, 0, x, positions,
+                                        pages, active)
+
+        x = jax.jit(model.embed_tokens)(params, tokens[:, None])
+        x, new_pool = _per_layer_reference(model, params, pool, x, block)
+        return jax.jit(model.logits)(params, x)[:, 0], new_pool
+
+    def new_decode(params, pool):
+        return model.decode_step(params, pool, tokens, positions,
+                                 pages=pages, active=active)
+
+    chunk = jnp.asarray(rng.integers(3, cfg.vocab, 8), jnp.int32)
+    row, start, n_valid = pages[1], 4, 6
+
+    def ref_chunk(params, pool):
+        pos = start + jnp.arange(chunk.shape[0])
+
+        def block(kind, lp, lc, x):
+            return T.prefill_chunk_block(cfg, kind, lp, lc, 0, x, pos, row,
+                                         n_valid)
+
+        x = jax.jit(model.embed_tokens)(params, chunk[None])
+        x, new_pool = _per_layer_reference(model, params, pool, x, block)
+        last = jnp.take(x, n_valid - 1, axis=1)
+        return jax.jit(model.logits)(params, last[:, None])[:, 0], new_pool
+
+    def new_chunk(params, pool):
+        return model.prefill_chunk(params, pool, row, chunk, start, n_valid)
+
+    for ref, new in ((ref_decode, new_decode), (ref_chunk, new_chunk)):
+        want = ref(params, pool)
+        got = jax.jit(new)(params, pool)
+        for w, g in zip(jax.tree_util.tree_leaves(want),
+                        jax.tree_util.tree_leaves(got)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        # the writes landed: not two untouched pools compared
+        assert not all(np.array_equal(np.asarray(a), np.asarray(b))
+                       for a, b in zip(jax.tree_util.tree_leaves(got[1]),
+                                       jax.tree_util.tree_leaves(pool)))
 
 
 # ---------------------------------------------------------------------------
