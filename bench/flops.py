@@ -3,7 +3,10 @@
 Training: 6·N·tokens for every parameter matrix product (N counts a tied
 embedding once: its lookup is free and its use as the head is one
 product), plus the causal attention scores and the SSD chunk terms, which
-no parameter count holds. Recomputation under remat does not count.
+no parameter count holds. Recomputation under remat does not count. N is
+the parameters a token's products use: a family's ``active_params(c)``
+where its reference module defines one (a mixture of experts: k of E
+routed experts and everything else), else every parameter.
 
 Serving: per processed token 2·N minus the head, the head once for every
 row whose logits are used, and attention over the live context.
@@ -15,6 +18,13 @@ import math
 
 def n_params(ref, c: dict) -> int:
     return sum(math.prod(shape) for shape, _ in ref.param_specs(c).values())
+
+
+def model_params(ref, c: dict) -> int:
+    """The parameters one token's products use (see the module's note)."""
+    if hasattr(ref, "active_params"):
+        return ref.active_params(c)
+    return n_params(ref, c)
 
 
 def _head_params(d) -> int:
@@ -43,7 +53,7 @@ def ssd_fwd(d, S: int) -> float:
 
 def train_step(ref, c: dict, batch: int, seq: int) -> float:
     d = ref.dims(c)
-    return (6.0 * n_params(ref, c) * batch * seq
+    return (6.0 * model_params(ref, c) * batch * seq
             + 3.0 * batch * (attention_fwd(d, seq) + ssd_fwd(d, seq)))
 
 
@@ -61,7 +71,7 @@ def serve_request(ref, c: dict, prompt_len: int, cached: int,
     with logits for the last, then ``n_gen - 1`` decode steps."""
     d = ref.dims(c)
     embedding = _head_params(d) * (1 if d.tied else 2)   # lookup + head
-    body = 2.0 * (n_params(ref, c) - embedding)
+    body = 2.0 * (model_params(ref, c) - embedding)
     head = 2.0 * _head_params(d)
     prefill = prompt_len - cached
     decode = max(n_gen - 1, 0)

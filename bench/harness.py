@@ -153,9 +153,23 @@ class Run:
 
     # -- weights ------------------------------------------------------------
     def weights(self) -> dict:
+        """The seed's weights; on a cell of several chips drawn into
+        :meth:`placement`, never whole on one chip."""
         from bench import weights
 
-        return weights.make(self.ref.param_specs(self.config), self.seed)
+        across = self.devices if len(self.devices) > 1 else None
+        return weights.make(self.ref.param_specs(self.config), self.seed,
+                            devices=across)
+
+    def placement(self) -> Optional[dict]:
+        """Where :meth:`weights` puts each leaf: None (the default device)
+        on one chip, else ``bench.weights.placement`` over the cell's."""
+        from bench import weights
+
+        if len(self.devices) == 1:
+            return None
+        return weights.placement(self.ref.param_specs(self.config),
+                                 self.devices)
 
     def to_program(self, flat: dict) -> dict:
         tree: Dict[str, Any] = {}

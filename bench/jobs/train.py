@@ -14,6 +14,12 @@ steps as a user's run does; it ends once the last step is done.
 
 Once the window has closed and the program's state is freed, the plain
 reference trains from the same weights on the same rows for three steps.
+
+A workload file that gives ``"plan"`` (a name in the program's plan
+catalog, or the fields of a custom plan) and ``"mesh"`` (axis sizes, e.g.
+``{"data": 4, "model": 1}``) trains on a mesh of the chips the harness
+took, through the registry's ``mesh_provider`` and ``sharding_plan`` as a
+user's run does; the seed's weights then come drawn across those chips.
 """
 from __future__ import annotations
 
@@ -41,9 +47,33 @@ def batches_of(rows: np.ndarray, batch: int, n: int):
              rows[k * batch:(k + 1) * batch, 1:]) for k in range(n)]
 
 
+def mesh_and_plan(ctx, registry):
+    """The workload's mesh over ``ctx.devices`` and its sharding plan, or
+    ``(None, None)`` where it gives neither."""
+    spec = ctx.spec
+    if "plan" not in spec and "mesh" not in spec:
+        return None, None
+    if "plan" not in spec or "mesh" not in spec:
+        raise ValueError(f"{ctx.name}: a workload gives both 'plan' and "
+                         f"'mesh', or neither")
+    from jax.sharding import Mesh
+
+    axes = spec["mesh"]
+    if int(np.prod(list(axes.values()))) != len(ctx.devices):
+        raise ValueError(f"{ctx.name}: mesh {axes} does not cover the "
+                         f"cell's {len(ctx.devices)} chips")
+    mesh = Mesh(np.asarray(ctx.devices).reshape(tuple(axes.values())),
+                tuple(axes))
+    plan = spec["plan"]
+    if isinstance(plan, str):
+        return mesh, registry.build("sharding_plan", plan)
+    return mesh, registry.build("sharding_plan", "custom", **plan)
+
+
 def build(ctx):
     """The program's objects, built through the registry as the run API
     builds them, and the data of this seed."""
+    import jax
     import jax.numpy as jnp
     from repro.config.registry import DEFAULT_REGISTRY as REG
     import repro.core.components  # noqa: F401  (populates the registry)
@@ -61,12 +91,22 @@ def build(ctx):
                         seq_len=S, seed=0, shuffle=False)
     loader = REG.build("loader", "sharded", dataset=dataset, global_batch=B)
     opt = REG.build("optimizer", "adamw", **spec["optimizer"])
+    mesh, plan = mesh_and_plan(ctx, REG)
+    sharded = {} if mesh is None else {"mesh_provider": mesh,
+                                       "sharding_plan": plan}
     gym = REG.build("gym", "standard", model=model, optimizer=opt,
                     loader=loader, prefetch=spec["prefetch"],
-                    log_every=spec["log_every"])
+                    log_every=spec["log_every"], **sharded)
     params = ctx.to_program(ctx.weights())
-    gym._init_state = lambda: {"params": params, "opt": opt.init(params),
-                               "step": jnp.zeros((), jnp.int32)}
+    if mesh is None:
+        gym._init_state = lambda: {"params": params, "opt": opt.init(params),
+                                   "step": jnp.zeros((), jnp.int32)}
+    else:
+        # from the weights' placement into the plan's layout of the state
+        gym._init_state = lambda: jax.jit(
+            lambda p: {"params": p, "opt": opt.init(p),
+                       "step": jnp.zeros((), jnp.int32)},
+            out_shardings=gym._state_sh)(params)
     state = gym.setup()
     del gym._init_state
     for hook in ctx.hooks:
@@ -140,13 +180,26 @@ def traced_steps(ctx, b, state):
 
 def log_step_memory(ctx, b, state):
     """The compiler's memory analysis of the train step at the window's
-    shapes (read from the compilation cache)."""
+    shapes (read from the compilation cache); on a mesh, with the state's
+    and the batch's shardings, the bytes of each chip."""
     import jax
 
-    batch = next(iter(b["gym"].loader.batches(1)))
-    shapes = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (state, batch))
-    ctx.log_memory("train_step", b["gym"]._jit_step.lower(*shapes))
+    gym = b["gym"]
+    batch = next(iter(gym.loader.batches(1)))
+    if gym.mesh is None:
+        shapes = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (state, batch))
+        ctx.log_memory("train_step", gym._jit_step.lower(*shapes))
+        return
+
+    def placed(a, sharding):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    shapes = (jax.tree_util.tree_map(lambda a: placed(a, a.sharding), state),
+              jax.tree_util.tree_map(placed, batch,
+                                     gym._batch_shardings(batch)))
+    ctx.log_memory(f"train_step, each of {len(ctx.devices)} chips",
+                   gym._jit_step.lower(*shapes))
 
 
 def program_update_norms(ctx, params_after):
@@ -156,7 +209,7 @@ def program_update_norms(ctx, params_after):
 
     from bench.reference.training import delta_norms
 
-    after = jax.device_put(params_after)
+    after = jax.device_put(params_after, ctx.placement())
     return delta_norms(after, ctx.weights())
 
 

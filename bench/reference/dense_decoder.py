@@ -25,6 +25,26 @@ import jax.numpy as jnp
 
 from .numerics import cross_entropy_sum, mm, rmsnorm
 
+#: The harness's own tests at CPU size (``bench/tests/tiny.py``): the
+#: published keys cut to a few dozen, and each job's limits at that size,
+#: set between the program's readings and the control's on seeds 1-4
+#: (``bench/calibrate.py`` on the CPU): serving, program <= 0.19 and
+#: control >= 0.70; training, loss 0.0046 / 0.0147 and gradient 0.023 /
+#: 0.023. The update norm does not separate the control at this size
+#: (0.0135 / 0.010); its limit catches a state left unchanged, which reads
+#: 1. The weights are ten times the published scale: at this width the
+#: served tokens then depend on their context, not only on the token
+#: before them.
+TINY = {
+    "config": {"hidden_size": 64, "num_attention_heads": 4,
+               "num_key_value_heads": 2, "num_hidden_layers": 2,
+               "intermediate_size": 128, "vocab_size": 512,
+               "initializer_range": 0.2},
+    "limits": {"train": {"loss_gap": 0.009, "grad_norm_gap": 0.05,
+                         "update_norm_gap": 0.5},
+               "serve": {"logit_gap": 0.4}},
+}
+
 
 def dims(c: dict) -> SimpleNamespace:
     H = c["num_attention_heads"]

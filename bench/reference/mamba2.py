@@ -32,6 +32,17 @@ from .numerics import cross_entropy_sum, mm, rmsnorm
 
 _SCAN_BLOCK = 64   # tokens per rematerialised block of the recurrence
 
+#: The harness's own tests at CPU size (``bench/tests/tiny.py``): the
+#: published keys cut to a few dozen (a nested group's keys merged into
+#: it). No Mamba-2 cell runs, so no limits are set at this size.
+TINY = {
+    "config": {"d_model": 64, "n_layer": 2, "vocab_size": 509,
+               "padded_vocab_size": 512,
+               "mamba2_module": {"headdim": 16, "d_state": 16,
+                                 "chunk_size": 32}},
+    "limits": {},
+}
+
 
 def dims(c: dict) -> SimpleNamespace:
     m = c["mamba2_module"]

@@ -64,3 +64,32 @@ def test_unknown_device_is_an_error():
     assert peaks.peak("TPU v5 lite")["flops_bf16"] == 197e12
     with pytest.raises(KeyError):
         peaks.peak("TPU v9 imaginary")
+
+
+def test_active_params_replace_the_parameter_count():
+    """A family that defines ``active_params`` (a mixture of experts: k of
+    E routed experts) is counted by it in training and serving; one that
+    does not, by every parameter."""
+    c = _config("qwen1.5-0.5b")
+    d = dense_decoder.dims(c)
+    total = flops.n_params(dense_decoder, c)
+    assert flops.model_params(dense_decoder, c) == total
+    active = total - 300_000_000
+
+    class Sparse:
+        dims = staticmethod(dense_decoder.dims)
+        param_specs = staticmethod(dense_decoder.param_specs)
+
+        @staticmethod
+        def active_params(c):
+            return active
+
+    assert flops.model_params(Sparse, c) == active
+    attn = 3 * 2 * 2 * (2048 ** 2 / 2) * d.H * d.dh * d.L * 6
+    assert flops.train_step(Sparse, c, 6, 2048) == pytest.approx(
+        6 * active * 6 * 2048 + attn, rel=1e-12)
+    body = 2 * (active - d.V * d.D)
+    want = 8 * body + 3 * 2 * d.V * d.D + 4 * sum(
+        t + 1 for t in range(4, 12)) * d.H * d.dh * d.L
+    assert flops.serve_request(Sparse, c, 10, 4, 3) == pytest.approx(
+        want, rel=1e-12)

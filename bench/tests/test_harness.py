@@ -84,6 +84,88 @@ def test_cell_and_metric_added_as_files_only(root, tmp_path):
     assert "tick_ms.serve_online" in out["metrics"]
 
 
+#: a third family, added as new files: a dense decoder under a name of its
+#: own, with its CPU size and an active-parameter count
+TWIN_REFERENCE = '''"""A dense decoder under a family name of its own."""
+import math
+
+from bench.reference.dense_decoder import (TINY, dims, head, hidden,  # noqa: F401
+                                           logits, loss_sum, param_specs)
+
+
+def active_params(c):
+    return sum(math.prod(s) for s, _ in param_specs(c).values())
+'''
+TWIN_PROGRAM = ('"""The program side of the twin family."""\n'
+                "from bench.program.dense_decoder import PATHS, arch_config"
+                "  # noqa: F401\n")
+
+
+def test_family_and_four_chip_cell_added_as_files_only(tmp_path):
+    """A new family (reference, adapter, configuration) and a training cell
+    of it with a plan and a mesh over four chips: new files plus entries in
+    ``BENCHMARK.json`` in a copy of the tree, nothing else edited. The copy's
+    tiny tree takes the family's CPU size from its module; the cell runs on
+    four forced CPU devices and reads correct."""
+    src = str(tmp_path / "src")
+    shutil.copytree(os.path.join(REPO, "bench"), os.path.join(src, "bench"),
+                    ignore=shutil.ignore_patterns(".run", ".dev",
+                                                  "__pycache__"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), src)
+    os.symlink(os.path.join(REPO, "src"), os.path.join(src, "src"))
+    b = os.path.join(src, "bench")
+    with open(os.path.join(b, "reference", "twin_decoder.py"), "w") as f:
+        f.write(TWIN_REFERENCE)
+    with open(os.path.join(b, "program", "twin_decoder.py"), "w") as f:
+        f.write(TWIN_PROGRAM)
+    with open(os.path.join(b, "configs", "qwen1.5-0.5b.json")) as f:
+        cfg = json.load(f)
+    cfg.update(name="twin-0.5b", family="twin_decoder")
+    with open(os.path.join(b, "configs", "twin-0.5b.json"), "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(b, "workloads",
+                           "qwen1.5-0.5b.train.seq2k.json")) as f:
+        spec = json.load(f)
+    spec.update(plan="fsdp", mesh={"data": 4, "model": 1}, global_batch=8)
+    cell = "twin-0.5b.train.fsdp4"
+    with open(os.path.join(b, "workloads", f"{cell}.json"), "w") as f:
+        json.dump(spec, f)
+    with open(os.path.join(src, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": "twin-0.5b", "source": "test",
+                             "file": "bench/configs/twin-0.5b.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": cell, "config": "twin-0.5b",
+                               "traffic": "pretrain_packed", "chips": 4,
+                               "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "qwen1.5-0.5b.train.seq2k" in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    with open(os.path.join(src, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    root = str(tmp_path / "tiny")
+    argv = ["--workload", cell, "--seed", "2147483659", "--seconds", "2",
+            "--trace", "0"]
+    code = ("import json, sys; from bench.tests import tiny; "
+            "tiny.make(sys.argv[1]); "
+            "print(json.dumps(tiny.run(sys.argv[1], sys.argv[2:])))")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([src, os.path.join(src, "src")]))
+    p = subprocess.run([sys.executable, "-c", code, root, *argv], cwd=src,
+                       env=env, capture_output=True, text=True, timeout=900)
+    assert p.returncode == 0, p.stderr[-4000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    with open(os.path.join(root, "bench", "configs", "twin-0.5b.json")) as f:
+        assert json.load(f)["hidden_size"] == 64
+    with open(os.path.join(root, "bench", "workloads", f"{cell}.json")) as f:
+        assert json.load(f)["mesh"] == {"data": 4, "model": 1}
+    assert out["correct"], out["checks"]
+    assert out["device"]["count"] == 4
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert {"setup_s", "train_tokens_per_s"} <= set(out["metrics"])
+
+
 def _run_cli(cwd, env_extra=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.update(env_extra or {})

@@ -38,6 +38,12 @@ def test_layer_of_unscoped_ops():
     assert T.layer_of("%copy.2", "jit(f)/mlp/copy", "") == "mlp"
 
 
+def _op(pid, layer, start, dur, name, collective=False):
+    """An op as ``read`` gives it: its program scope is its layer's."""
+    return (pid, layer, start, dur, name,
+            layer if layer in T.SCOPES else None, collective)
+
+
 def _two_programs():
     """A train-like program (id 1) run twice, a while op holding its body,
     and a tick program (id 2) run twice with an unscoped copy; ns."""
@@ -47,16 +53,16 @@ def _two_programs():
                        ("jit_engine_step", 2, 500.0, 50.0)]}
     ops = {PLANE: []}
     for t0 in (0.0, 200.0):
-        ops[PLANE] += [(1, "none", t0, 60.0, "%while"),
-                       (1, "attention", t0 + 10, 20.0, "%fusion.1"),
-                       (1, "mlp", t0 + 30, 30.0, "%fusion.2"),
-                       (1, "optimizer", t0 + 60, 20.0, "%fusion.3"),
-                       (1, "copy", t0 + 80, 20.0, "%copy.1")]
+        ops[PLANE] += [_op(1, "none", t0, 60.0, "%while"),
+                       _op(1, "attention", t0 + 10, 20.0, "%fusion.1"),
+                       _op(1, "mlp", t0 + 30, 30.0, "%fusion.2"),
+                       _op(1, "optimizer", t0 + 60, 20.0, "%fusion.3"),
+                       _op(1, "copy", t0 + 80, 20.0, "%copy.1")]
     for t0 in (400.0, 500.0):
-        ops[PLANE] += [(2, "kv_read", t0, 10.0, "%fusion.1"),
-                       (2, "copy", t0 + 10, 20.0, "%copy.1"),
-                       (2, "mlp", t0 + 30, 15.0, "%fusion.2"),
-                       (2, "sample", t0 + 45, 5.0, "%fusion.3")]
+        ops[PLANE] += [_op(2, "kv_read", t0, 10.0, "%fusion.1"),
+                       _op(2, "copy", t0 + 10, 20.0, "%copy.1"),
+                       _op(2, "mlp", t0 + 30, 15.0, "%fusion.2"),
+                       _op(2, "sample", t0 + 45, 5.0, "%fusion.3")]
     return ops, modules
 
 
@@ -117,8 +123,8 @@ def test_idle_by_phase_and_scheduler_gap():
 def test_program_without_names_reads_nothing():
     """A trace of a program with no scopes and no phase spans (the parent
     of the change that added them) gives every reader None."""
-    ops = {PLANE: [(1, "none", 0.0, 10.0, "%fusion"),
-                   (1, "copy", 10.0, 5.0, "%copy.1")]}
+    ops = {PLANE: [_op(1, "none", 0.0, 10.0, "%fusion"),
+                   _op(1, "copy", 10.0, 5.0, "%copy.1")]}
     modules = {PLANE: [("jit_step", 1, 0.0, 15.0)]}
     s = T.summarize(ops, modules, [("bench/window", 0.0, 20.0),
                                    ("bench/tick", 0.0, 15.0)])
@@ -160,3 +166,78 @@ def test_scoped_fixture():
     assert labels.get("after serve/tick", 0) > 0
     assert sum(labels.values()) == pytest.approx(s["idle_s"])
     assert s["sched_gap_s"] > 0
+
+
+def test_program_scopes_and_collectives():
+    """``scopes_s`` reads the program's own scope names, one that
+    :data:`SCOPES` lacks among them, and ``collectives_s`` the exchanges
+    between chips; both averaged over the device planes."""
+    assert "experts" not in T.SCOPES
+    assert T.scope_of("jit(f)/mlp/experts/dot_general",
+                      T.SCOPES + ("experts",)) == "experts"
+    assert T.scope_of("jit(f)/mlp/experts/dot_general") == "mlp"
+    assert T.is_collective("%all-gather-start.3")
+    assert T.is_collective("%all-to-all.1")
+    assert T.is_collective("%fusion.7", "all-reduce fusion")
+    assert not T.is_collective("%fusion.7", "loop fusion")
+    assert not T.is_collective("%copy-start.2", "copy-start")
+    modules, ops = {}, {}
+    for k in range(2):          # two chips, the second twice as slow
+        plane = f"/device:TPU:{k}"
+        f = 1 + k
+        modules[plane] = [("jit_train_step", 1, 0.0, 100.0 * f)]
+        ops[plane] = [
+            (1, "mlp", 0.0, 30.0 * f, "%fusion.1", "experts", False),
+            (1, "mlp", 30.0 * f, 10.0 * f, "%fusion.2", "mlp", False),
+            (1, "none", 40.0 * f, 20.0 * f, "%all-to-all.1 [all-to-all]",
+             None, True),
+            (1, "attention", 60.0 * f, 10.0 * f, "%all-gather-done.1",
+             "attention", True),
+            (1, "attention", 70.0 * f, 30.0 * f, "%fusion.3", "attention",
+             False)]
+    host = [("bench/window", 0.0, 200.0), ("gym/step", 0.0, 200.0)]
+    s = T.summarize(ops, modules, host)
+    assert s["scopes_s"] == pytest.approx(
+        {"experts": 45e-9, "mlp": 15e-9, "attention": 60e-9})
+    assert s["collectives_s"] == pytest.approx(45e-9)
+    # the bench's own copy of the names folds experts into mlp
+    assert s["layers_s"]["mlp"] == pytest.approx(60e-9)
+    assert T.scope_ms_per_step(s, ("experts",)) == pytest.approx(45e-6)
+    assert T.scope_ms_per_step(s, ("route",)) is None
+    assert T.collective_ms_per_step(s) == pytest.approx(45e-6)
+
+
+def test_scopes_equal_layers_without_new_names():
+    """With the program's scopes the bench's own, ``scopes_s`` is
+    ``layers_s`` without ``copy`` and ``none``; one chip has no exchange."""
+    ops, modules = _two_programs()
+    s = T.summarize(ops, modules, [("bench/window", 0.0, 600.0),
+                                   ("gym/step", 0.0, 300.0)])
+    assert s["scopes_s"] == {k: v for k, v in s["layers_s"].items()
+                             if k in T.SCOPES}
+    assert s["collectives_s"] == 0.0
+    assert T.collective_ms_per_step(s) is None
+    path = os.path.join(DATA, "tpu_v5e_scoped.xplane.pb")
+    r = T.summarize(*T.read(path))
+    assert r["scopes_s"] == {k: v for k, v in r["layers_s"].items()
+                             if k in T.SCOPES}
+    assert r["collectives_s"] == 0.0
+
+
+def test_plane_shifts_match_runs_per_device():
+    """Each device counts its own runs (as on a four-chip v5e, where chip 0
+    was at run 53 while chips 1-3 were at 51): a plane is matched to its
+    own device's completions, never to another's run of the same id."""
+    step, lag = 220e6, 2e6
+    ends, completions = {}, {}
+    for dev, first in enumerate((53, 51, 51, 51)):
+        plane = f"/device:TPU:{dev}"
+        ends[plane] = [(first + k, 407e6 + k * step) for k in range(4)]
+        for run, end in ends[plane]:
+            completions[(dev, run)] = end + lag + dev * 1e3
+    shifts = T.plane_shifts(ends, completions)
+    for dev in range(4):
+        shift, n = shifts[f"/device:TPU:{dev}"]
+        assert n == 4 and shift == pytest.approx(lag + dev * 1e3)
+    assert T.plane_shifts({"/device:TPU:1": [(99, 1.0)]}, completions) == {
+        "/device:TPU:1": (0.0, 0)}

@@ -1,29 +1,31 @@
 """A copy of the benchmark's data files at CPU size, for the harness's own
 tests: the same cells, configurations and mixes with every width cut to a
-few dozen."""
+few dozen.
+
+Each family brings its own CPU size and limits: ``TINY = {"config": {...},
+"limits": {"train": {...}, "serve": {...}}}`` in its reference module,
+``bench/reference/<family>.py``. A config file's keys are replaced by
+``TINY["config"]``'s, and a nested group's keys merged into it. A cell of
+several chips runs in a subprocess on as many forced CPU devices.
+
+    python -m bench.tests.tiny <root> <bench/run.py arguments>
+"""
 from __future__ import annotations
 
+import importlib
 import io
 import json
 import os
 import shutil
+import subprocess
+import sys
 import time
 from contextlib import redirect_stdout
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-
-#: Limits at this size, set between the program's readings and the
-#: control's on seeds 1-4 (CPU, ``bench/calibrate.py``'s readings at the
-#: tiny sizes): serving, program <= 0.19 and control >= 0.70; training,
-#: loss 0.0046 / 0.0147 and gradient 0.023 / 0.023. The update norm does
-#: not separate the control at this size (0.0135 / 0.010); its limit
-#: catches a state left unchanged, which reads 1.
-LIMITS = {
-    "serve": {"logit_gap": 0.4},
-    "dense_decoder": {"loss_gap": 0.009, "grad_norm_gap": 0.05,
-                      "update_norm_gap": 0.5},
-}
+#: the job kinds whose limits a family's ``TINY["limits"]["serve"]`` gives
+SERVE_JOBS = ("serve_online", "serve_batch")
 
 
 def _dump(obj, path):
@@ -31,52 +33,54 @@ def _dump(obj, path):
         json.dump(obj, f)
 
 
+def _load(*parts):
+    with open(os.path.join(*parts)) as f:
+        return json.load(f)
+
+
+def cut(cfg: dict, tiny: dict) -> dict:
+    """``cfg`` with ``tiny``'s keys, a nested group merged key by key."""
+    for k, v in tiny.items():
+        if isinstance(v, dict) and isinstance(cfg.get(k), dict):
+            cfg[k] = dict(cfg[k], **v)
+        else:
+            cfg[k] = v
+    return cfg
+
+
 def make(root: str) -> dict:
     """Write ``BENCHMARK.json`` and ``bench/{configs,workloads,traffic,
-    metrics}`` under ``root``; returns the benchmark."""
+    metrics}`` under ``root``, cut to CPU size; returns the benchmark."""
     for d in ("configs", "workloads", "traffic"):
         os.makedirs(os.path.join(root, "bench", d), exist_ok=True)
     shutil.copytree(os.path.join(REPO, "bench", "metrics"),
                     os.path.join(root, "bench", "metrics"),
                     dirs_exist_ok=True)
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    bench = _load(REPO, "BENCHMARK.json")
+    tiny = {}
     for name in sorted(os.listdir(os.path.join(REPO, "bench", "configs"))):
         path = os.path.join("bench", "configs", name)
-        with open(os.path.join(REPO, path)) as f:
-            cfg = json.load(f)
-        if cfg["family"] == "dense_decoder":
-            # weights ten times the published scale: at this width the
-            # served tokens then depend on their context, not only on the
-            # token before them
-            cfg.update(hidden_size=64, num_attention_heads=4,
-                       num_key_value_heads=2, num_hidden_layers=2,
-                       intermediate_size=128, vocab_size=512,
-                       initializer_range=0.2)
-        else:
-            cfg.update(d_model=64, n_layer=2, vocab_size=509,
-                       padded_vocab_size=512)
-            cfg["mamba2_module"].update(headdim=16, d_state=16, chunk_size=32)
-        _dump(cfg, os.path.join(root, path))
-    families = {}
-    for c in bench["configs"]:
-        with open(os.path.join(REPO, c["file"])) as f:
-            families[c["name"]] = json.load(f)["family"]
+        cfg = _load(REPO, path)
+        fam = cfg["family"]
+        tiny[fam] = importlib.import_module(f"bench.reference.{fam}").TINY
+        _dump(cut(cfg, tiny[fam]["config"]), os.path.join(root, path))
+    families = {c["name"]: _load(REPO, c["file"])["family"]
+                for c in bench["configs"]}
     for w in bench["workloads"]:
-        with open(os.path.join(REPO, "bench", "workloads",
-                               f"{w['name']}.json")) as f:
-            s = json.load(f)
+        s = _load(REPO, "bench", "workloads", f"{w['name']}.json")
+        limits = tiny[families[w["config"]]]["limits"]
         if s["job"] == "train":
             s.update(global_batch=4, seq_len=64, data_steps=8, trace_steps=2,
-                     limits=LIMITS[families[w["config"]]])
-        else:
+                     limits=limits["train"])
+        elif s["job"] in SERVE_JOBS:
             s.update(n_slots=4, max_len=256, prefill_chunk=32, max_output=64,
                      check_tokens=40, trace_seconds=0.5,
-                     limits=LIMITS["serve"])
+                     limits=limits["serve"])
+        else:
+            raise ValueError(f"no CPU size for job {s['job']!r}")
         _dump(s, os.path.join(root, "bench", "workloads", f"{w['name']}.json"))
     for t in {w["traffic"] for w in bench["workloads"]}:
-        with open(os.path.join(REPO, "bench", "traffic", f"{t}.json")) as f:
-            m = json.load(f)
+        m = _load(REPO, "bench", "traffic", f"{t}.json")
         if m["kind"] == "requests":
             m["rate_per_s"] = 6.0
             if "system_prompts" in m:
@@ -89,9 +93,8 @@ def make(root: str) -> dict:
     return bench
 
 
-def run(root: str, argv, hooks=()) -> dict:
-    """One in-process run on the CPU (the look for a chip skipped); the
-    parsed result line."""
+def _in_process(root: str, argv, chips: int, hooks=()) -> str:
+    """One run on the CPU (the look for a chip skipped); its stdout."""
     import jax
 
     from bench import harness
@@ -99,7 +102,36 @@ def run(root: str, argv, hooks=()) -> dict:
     buf = io.StringIO()
     with redirect_stdout(buf):
         rc = harness.main(list(argv), t_start=time.perf_counter(),
-                          devices=jax.devices()[:1], root=root,
+                          devices=jax.devices()[:chips], root=root,
                           hooks=hooks)
     assert rc == 0, rc
-    return json.loads(buf.getvalue().strip().splitlines()[-1])
+    return buf.getvalue()
+
+
+def run(root: str, argv, hooks=()) -> dict:
+    """One run of a cell of the tree at ``root``; the parsed result line. A
+    cell of one chip runs in this process; one of several in a subprocess
+    with as many forced CPU devices (no hooks reach it there)."""
+    cell = argv[argv.index("--workload") + 1]
+    chips = {w["name"]: w["chips"]
+             for w in _load(root, "BENCHMARK.json")["workloads"]}[cell]
+    if chips == 1:
+        out = _in_process(root, argv, 1, hooks)
+    else:
+        assert not hooks, "hooks reach only a cell of one chip"
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS=f"--xla_force_host_platform_device_count={chips}",
+                   PYTHONPATH=os.pathsep.join([REPO, os.path.join(REPO, "src")]))
+        p = subprocess.run([sys.executable, "-m", "bench.tests.tiny", root,
+                            *argv], cwd=REPO, env=env, capture_output=True,
+                           text=True, timeout=600)
+        assert p.returncode == 0, p.stderr[-4000:]
+        out = p.stdout
+    return json.loads(out.strip().splitlines()[-1])
+
+
+if __name__ == "__main__":
+    import jax
+
+    _root, _argv = sys.argv[1], sys.argv[2:]
+    print(_in_process(_root, _argv, len(jax.devices())), end="", flush=True)

@@ -14,6 +14,15 @@ phase.
   (``jax.profiler.TraceAnnotation``). Each device-idle interval goes to
   the innermost span covering it, else to ``after <the span that ended
   last>``.
+- Scope by the program's own names: the op's innermost name of the
+  program's ``repro.telemetry.spans.SCOPES``, read when the trace is
+  reduced (``scopes_s``), so that a scope a later program adds (an MoE's
+  ``route`` inside ``mlp``) reads with no edit here. The bench's own copy,
+  :data:`SCOPES`, keeps ``layers_s`` and its partitions as they are.
+- Collective: an op whose name or category is an exchange between chips
+  (all-gather, all-reduce, reduce-scatter, all-to-all, collective-permute,
+  with their ``-start`` / ``-done`` parts; ``collectives_s``): the time the
+  core spends in or waiting for one.
 
 ``jax.profiler.ProfileData`` gives the events but not their metadata's
 stats, so :func:`op_metadata` decodes that part of the file itself: the
@@ -66,26 +75,37 @@ TRAIN_GROUPS = {
 #: the decode tick's partition
 TICK_GROUPS = {"kv": ("kv_read", "kv_write"), "copy": ("copy",)}
 
+#: the exchanges between chips, as XLA names their ops and categories
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+               "collective-permute")
+
 _WRAPPED = re.compile(r"[\w.-]+\((.*)\)")
 _MODULE = re.compile(r"(.*)\((\d+)\)$")
 _COPY_NAME = re.compile(r"%copy(-start|-done)?(\.\d+)?$")
+_COLLECTIVE = re.compile(r"%?(" + "|".join(COLLECTIVES) + r")\b")
 
 Interval = Tuple[float, float]
 
 
 # -- layer of one op ----------------------------------------------------------
-def scope_of(tf_op: str) -> Optional[str]:
-    """The innermost :data:`SCOPES` name in a JAX name stack, the program's
-    own ``jit(...)`` left out; None when there is none."""
+def scope_of(tf_op: str, names: Sequence[str] = SCOPES) -> Optional[str]:
+    """The innermost of ``names`` in a JAX name stack, the program's own
+    ``jit(...)`` left out; None when there is none."""
     found = None
     for part in tf_op.split("/")[1:]:
         m = _WRAPPED.fullmatch(part)
         while m:
             part = m.group(1)
             m = _WRAPPED.fullmatch(part)
-        if part in SCOPES:
+        if part in names:
             found = part
     return found
+
+
+def is_collective(name: str, category: str = "") -> bool:
+    """Whether an op exchanges data between chips, by its HLO name
+    (``%all-gather-start.3``) or its category."""
+    return bool(_COLLECTIVE.match(name) or _COLLECTIVE.search(category))
 
 
 def layer_of(name: str, tf_op: str, category: str) -> str:
@@ -204,26 +224,27 @@ def op_metadata(path: str) -> Dict[str, Dict[Tuple[int, str], Tuple[str, str]]]:
 # -- events -------------------------------------------------------------------
 def read(path: str):
     """``(ops, modules, host)`` of the trace at ``path``: per device plane
-    the ops ``(program_id or None, layer, start_ns, dur_ns, name)`` (an
-    unscoped op's name with its ``[hlo_category]``) and the
-    program executions ``(program name, program_id, start_ns, dur_ns)``;
+    the ops ``(program_id or None, layer, start_ns, dur_ns, name, scope,
+    collective)`` (an unscoped op's name with its ``[hlo_category]``; its
+    innermost name of the program's ``repro.telemetry.spans.SCOPES`` or
+    None; whether it is an exchange between chips) and the program
+    executions ``(program name, program_id, start_ns, dur_ns)``;
     the host's ``gym/*``, ``serve/*`` and ``bench/window`` spans
     ``(name, start_ns, end_ns)``.
 
     A TPU trace puts the device's events about a millisecond early against
     the host's (an execution starts before the host call that launched
-    it). So each device plane is shifted onto the host clock by the least
-    time from an execution's end to the host's ``CompleteCallbacks`` event
-    of the same ``run_id``: after the shift no execution ends after the
-    host heard that it ended."""
+    it). So each device plane is shifted onto the host clock by
+    :func:`plane_shifts`."""
     import jax
+    from repro.telemetry.spans import SCOPES as names
 
     xplane = find_xplane(path)
     meta = op_metadata(xplane)
     pd = jax.profiler.ProfileData.from_file(xplane)
     ops, modules, host = {}, {}, []
     ends: Dict[str, List[Tuple[int, float]]] = {}   # plane -> (run_id, end)
-    completions: Dict[int, float] = {}
+    completions: Dict[Tuple[int, int], float] = {}  # (device, run_id) -> t
     for plane in pd.planes:
         if plane.name.startswith(DEVICE_PREFIX):
             lines = {ln.name: ln for ln in plane.lines}
@@ -234,7 +255,7 @@ def read(path: str):
                 if m:
                     mods.append((m.group(1), int(m.group(2)),
                                  float(e.start_ns), float(e.duration_ns)))
-                    run = _run_id(e)
+                    run = _stat(e, "run_id")
                     if run is not None:
                         ends.setdefault(plane.name, []).append(
                             (int(run), float(e.start_ns + e.duration_ns)))
@@ -250,9 +271,10 @@ def read(path: str):
                 name = e.name.split(" = ")[0]
                 tf_op, cat = pm.get((pid, name), ("", ""))
                 layer = layer_of(name, tf_op, cat)
+                tags = (scope_of(tf_op, names), is_collective(name, cat))
                 if layer in ("copy", "none") and cat:
                     name = f"{name} [{cat}]"    # for the unscoped-op table
-                evs.append((pid, layer, s, d, name))
+                evs.append((pid, layer, s, d, name) + tags)
             ops[plane.name], modules[plane.name] = evs, mods
         elif plane.name.startswith("/host:"):
             for ln in plane.lines:
@@ -261,25 +283,45 @@ def read(path: str):
                         s = float(e.start_ns)
                         host.append((e.name, s, s + float(e.duration_ns)))
                     elif e.name == "CompleteCallbacks":
-                        run = _run_id(e)
+                        run = _stat(e, "run_id")
                         if run is not None:
-                            completions[int(run)] = min(
+                            key = (int(_stat(e, "device_ordinal") or 0),
+                                   int(run))
+                            completions[key] = min(
                                 float(e.start_ns),
-                                completions.get(int(run), float("inf")))
+                                completions.get(key, float("inf")))
+    shifts = plane_shifts(ends, completions)
     for plane in ops:
-        lags = [completions[r] - end for r, end in ends.get(plane, ())
-                if r in completions]
-        shift = min(lags) if lags else 0.0
+        shift, n = shifts.get(plane, (0.0, 0))
         print(f"trace_scopes: {plane} shifted by {shift * 1e-6:.3f} ms onto "
-              f"the host clock ({len(lags)} executions matched)",
+              f"the host clock ({n} executions matched)",
               file=sys.stderr, flush=True)
-        ops[plane] = [(p, la, s + shift, d, n) for p, la, s, d, n in ops[plane]]
+        ops[plane] = [(p, la, s + shift, d, *tags)
+                      for p, la, s, d, *tags in ops[plane]]
         modules[plane] = [(n, p, s + shift, d) for n, p, s, d in modules[plane]]
     return ops, modules, host
 
 
-def _run_id(event) -> Optional[int]:
-    return next((v for k, v in event.stats if k == "run_id"), None)
+def plane_shifts(ends: Dict[str, List[Tuple[int, float]]],
+                 completions: Dict[Tuple[int, int], float]
+                 ) -> Dict[str, Tuple[float, int]]:
+    """plane -> (shift, executions matched): the least time from an
+    execution's end on ``/device:TPU:<k>`` to the host's
+    ``CompleteCallbacks`` of the same ``run_id`` on device ``k`` (each
+    device counts its own runs), so that after the shift no execution ends
+    after the host heard that it ended. A plane with no match is not
+    shifted."""
+    out = {}
+    for plane, runs in ends.items():
+        device = int(plane.rsplit(":", 1)[1])
+        lags = [completions[(device, r)] - end for r, end in runs
+                if (device, r) in completions]
+        out[plane] = (min(lags) if lags else 0.0, len(lags))
+    return out
+
+
+def _stat(event, name: str):
+    return next((v for k, v in event.stats if k == name), None)
 
 
 # -- idle time by phase ---------------------------------------------------------
@@ -325,10 +367,12 @@ def attribute(gaps: Sequence[Interval], spans: Sequence[Tuple[str, float, float]
 def summarize(ops, modules, host, window: Optional[Interval] = None) -> Dict:
     """Seconds, averaged over the device planes. ``window``: the traced
     window (default: the ``bench/window`` span, else the extent of the
-    device ops)."""
+    device ops). ``ops``, ``modules`` and ``host`` as :func:`read` gives
+    them."""
     if window is None:
         w = [(s, e) for n, s, e in host if n == WINDOW]
-        allev = [(s, s + d) for evs in ops.values() for _, _, s, d, _ in evs]
+        allev = [(s, s + d) for evs in ops.values()
+                 for _, _, s, d, *_ in evs]
         if w:
             window = (min(s for s, _ in w), max(e for _, e in w))
         elif allev:
@@ -347,16 +391,22 @@ def summarize(ops, modules, host, window: Optional[Interval] = None) -> Dict:
     tick_ids = _tick_programs(modules, spans)
     n = len(ops)
     layers: Dict[str, float] = {}
+    scopes: Dict[str, float] = {}
+    collectives = 0.0
     programs: Dict[str, Dict] = {}
     idle_labels: Dict[str, float] = {}
     idle = sched_free = busy = 0.0
     for plane in sorted(ops):
-        evs = [((pid, layer, name), c[0][0], c[0][1] - c[0][0])
-               for pid, layer, s, d, name in ops[plane]
+        evs = [((pid, layer, name, scope, coll), c[0][0], c[0][1] - c[0][0])
+               for pid, layer, s, d, name, scope, coll in ops[plane]
                for c in [clip([(s, s + d)], lo, hi)] if c]
-        for (pid, layer, name), t in self_times(evs).items():
+        for (pid, layer, name, scope, coll), t in self_times(evs).items():
             t /= n
             layers[layer] = layers.get(layer, 0.0) + t
+            if scope is not None:
+                scopes[scope] = scopes.get(scope, 0.0) + t
+            if coll:
+                collectives += t
             p = programs.setdefault(names.get(pid, "(no program)"),
                                     {"runs": 0, "module_s": 0.0, "ops_s": 0.0,
                                      "layers": {}, "unscoped": {},
@@ -370,7 +420,8 @@ def summarize(ops, modules, host, window: Optional[Interval] = None) -> Dict:
                 p = programs[names[pid]]
                 p["runs"] += 1.0 / n
                 p["module_s"] += d / n
-        on = union(clip([(s, s + d) for _, _, s, d, _ in ops[plane]], lo, hi))
+        on = union(clip([(s, s + d) for _, _, s, d, *_ in ops[plane]], lo,
+                        hi))
         busy += sum(e - s for s, e in on) / n
         edges = [lo] + [x for iv in on for x in iv] + [hi]
         gaps = [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
@@ -386,6 +437,8 @@ def summarize(ops, modules, host, window: Optional[Interval] = None) -> Dict:
         "busy_s": busy * ns,
         "idle_s": idle * ns,
         "layers_s": {k: v * ns for k, v in layers.items()},
+        "scopes_s": {k: v * ns for k, v in scopes.items()},
+        "collectives_s": collectives * ns,
         "programs": {k: {"runs": round(p["runs"]), "module_s": p["module_s"] * ns,
                          "ops_s": p["ops_s"] * ns, "tick": p["tick"],
                          "layers_s": {x: t * ns for x, t in p["layers"].items()},
@@ -456,6 +509,26 @@ def train_ms_per_step(s: Optional[Dict], group: str) -> Optional[float]:
     return 1e3 * t / s["steps"]
 
 
+def scope_ms_per_step(s: Optional[Dict], names: Sequence[str]
+                      ) -> Optional[float]:
+    """Device self-time of the program's scopes ``names`` (``scopes_s``)
+    per ``gym/step`` span, in ms; None where none of them ran."""
+    if s is None or not s["steps"]:
+        return None
+    got = [s["scopes_s"][k] for k in names if k in s["scopes_s"]]
+    if not got:
+        return None
+    return 1e3 * sum(got) / s["steps"]
+
+
+def collective_ms_per_step(s: Optional[Dict]) -> Optional[float]:
+    """Device self-time of the collective ops (``collectives_s``) per
+    ``gym/step`` span, in ms; None where no exchange ran (one chip)."""
+    if s is None or not s["steps"] or not s["collectives_s"]:
+        return None
+    return 1e3 * s["collectives_s"] / s["steps"]
+
+
 def tick_ms(s: Optional[Dict], part: str) -> Optional[float]:
     """The decode tick program's device self-time per tick, in ms:
     ``kv`` (``kv_read`` + ``kv_write``), ``copy`` (unscoped copies) or
@@ -498,6 +571,10 @@ def log_tables(s: Dict, out=sys.stderr) -> None:
         top = sorted(prog["unscoped_s"].items(), key=lambda kv: -kv[1])[:8]
         for op, t in top:
             p(f"trace_scopes:     unscoped {op} {1e3 * t / runs:.3f} ms a run")
+    if s["collectives_s"]:
+        p(f"trace_scopes: collectives {1e3 * s['collectives_s']:.3f} ms of "
+          f"ops ({100 * s['collectives_s'] / max(s['busy_s'], 1e-30):.1f} % "
+          f"of busy)")
     for label, t in sorted(s["idle_by_phase_s"].items(), key=lambda kv: -kv[1]):
         p(f"trace_scopes: idle under {label}: {1e3 * t:.3f} ms "
           f"({100 * t / max(s['idle_s'], 1e-30):.1f} % of idle)")

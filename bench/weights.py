@@ -4,6 +4,11 @@ The program and the references are both given these weights, made from the
 same seed by the same function, so the reference never takes anything the
 program made. The names and shapes come from the reference family's
 ``param_specs``; the program's adapter only rearranges the arrays.
+
+A cell of several chips draws each leaf straight into its :func:`placement`
+over those chips, so that no chip holds the whole float32 model. The
+values are the one-device draw's, bit for bit (JAX's partitionable
+threefry draws each element from its own index).
 """
 from __future__ import annotations
 
@@ -42,15 +47,38 @@ def _draw(key, shape, init):
     raise ValueError(f"unknown init {init!r}")
 
 
-def make(specs: dict, seed: int) -> dict:
-    """name -> float32 array on the default device, for ``specs`` of
-    ``name -> (shape, init)``. A ``log_uniform`` init is drawn as the log of
-    the value (the parameter is stored as a logarithm)."""
+def placement(specs: dict, devices) -> dict:
+    """name -> sharding over ``devices``: each leaf split along its first
+    axis that the number of devices divides, replicated where none does.
+    A stacked ``layers/`` leaf is split after its layer axis where it can
+    be, so that a scan over the layers slices it without gathering it."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(devices), ("chips",))
+    n = len(devices)
+    out = {}
+    for name, (shape, _) in specs.items():
+        order = list(range(len(shape)))
+        if name.startswith("layers/"):
+            order = order[1:] + order[:1]
+        axis = next((i for i in order if shape[i] % n == 0), None)
+        spec = () if axis is None else (None,) * axis + ("chips",)
+        out[name] = NamedSharding(mesh, P(*spec))
+    return out
+
+
+def make(specs: dict, seed: int, devices=None) -> dict:
+    """name -> float32 array, for ``specs`` of ``name -> (shape, init)``: on
+    the default device, or with ``devices`` (more than one) in their
+    :func:`placement`. A ``log_uniform`` init is drawn as the log of the
+    value (the parameter is stored as a logarithm)."""
     names = sorted(specs)
 
-    @jax.jit
     def gen(key):
         return {n: _draw(jax.random.fold_in(key, i), tuple(specs[n][0]),
                          specs[n][1]) for i, n in enumerate(names)}
 
-    return gen(seed_key(seed, "weights"))
+    if devices is None:
+        return jax.jit(gen)(seed_key(seed, "weights"))
+    return jax.jit(gen, out_shardings=placement(specs, devices))(
+        seed_key(seed, "weights"))
